@@ -14,18 +14,19 @@ from __future__ import annotations
 import gzip
 import sys
 
-from benchmarks.roofline import LINK_BW, RING_FACTOR
+from benchmarks.roofline import DRYRUN_DEVICE_KIND, RING_FACTOR, peaks
 from repro.launch import hloparse
 
 
 def site_report(text: str, top: int = 25):
     costs = hloparse.module_costs(text)
+    link_bw = peaks(DRYRUN_DEVICE_KIND)["link_bw"]
     rows = []
     for kind, b, g, m, name in costs.collective_sites:
         ring = RING_FACTOR.get(kind, lambda g: 1.0)(max(int(g), 1))
         rows.append({
             "kind": kind, "bytes": b, "mult": m, "group": g,
-            "seconds": b * m * ring / LINK_BW,
+            "seconds": b * m * ring / link_bw,
             "op_name": name,
         })
     rows.sort(key=lambda r: -r["seconds"])

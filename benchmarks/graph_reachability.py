@@ -9,11 +9,11 @@ CSR snapshot, then answer batches of ``reachable(u, v)`` pairs.
 Engine/impl columns:
 
   oracle / python        — pure-Python sequential BFS per query (ground truth)
-  batched / reference    — jitted CSR frontier engine, pure-jnp expansion
-  batched / kernel[...]  — same engine through the Pallas frontier kernel
-                           (``kernel`` on TPU; ``kernel_interpret`` anywhere
-                           with ``--kernels``, exercising the identical code
-                           through the interpreter)
+  batched / xla          — jitted CSR frontier engine, XLA expansion (the
+                           implementation every backend runs)
+  batched / kernel_interpret — same engine through the Pallas frontier
+                           kernel in the interpreter (``--kernels``; the TPU
+                           compiler refuses the kernel, docs/KERNELS.md)
 
 Maintenance rows (engine ``maintenance``) time the two table-maintenance
 hot paths:
@@ -22,7 +22,7 @@ hot paths:
   query-heavy mix: ``rebuild`` pays a full ``build_csr`` per batch,
   ``delta_host`` folds the batch with the numpy splice (O(valid edges)
   lexsort + host round-trip), ``delta_device`` with the fused device
-  searchsorted merge (``repro.core.maintenance.delta_merge``).  The
+  splice (``repro.core.maintenance.delta_merge``).  The
   ``batch`` column sweeps the update-batch size: the device fold's cost
   should track the batch, not the live-edge count.
 * growth rehash (``rehash_host`` vs ``rehash_device``, ``batch`` = 0):
@@ -236,8 +236,6 @@ def _bench_maintenance(
         csr = traversal.apply_delta(csr, g.state, ops, us, vs, impl="host")
     jax.block_until_ready(csr.src)
     impls = [("delta_host", "host"), ("delta_device", "device")]
-    if kernels and jax.default_backend() != "tpu":
-        impls.append(("delta_device_interpret", "device_interpret"))
     for pre, state, ops, us, vs in steps:
         jax.block_until_ready(traversal.build_csr(state))
         for _, impl in impls[1:]:
@@ -338,10 +336,8 @@ def run(
     shard_counts=(1, 4),
     obs_out: Dict = None,
 ) -> List[Dict]:
-    impls = [("reference", "reference")]  # explicit: impl=None auto-picks the kernel on TPU
-    if jax.default_backend() == "tpu":
-        impls.append(("kernel", "kernel"))
-    elif kernels:
+    impls = [("xla", "xla")]
+    if kernels:
         impls.append(("kernel_interpret", "kernel_interpret"))
     rows = []
     for key_space in graph_sizes:

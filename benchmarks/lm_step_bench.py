@@ -15,13 +15,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_NAMES, get_smoke_config
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import build_decode_step, build_train_step
 from repro.models import LM
 from repro.optim import adamw_init
 
 
 def bench_arch(name: str, steps: int = 3):
-    with jax.make_mesh((1, 1), ("data", "model")):
+    with jax.set_mesh(make_mesh((1, 1), ("data", "model"))):
         return _bench_arch(name, steps)
 
 

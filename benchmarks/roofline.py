@@ -1,10 +1,11 @@
 """Three-term roofline per (arch × shape × mesh) from the compiled dry-run.
 
-Terms (TPU v5e constants; per-device program, so per-chip peak rates):
+Terms (per-device program, so per-chip peak rates from :data:`PEAKS`,
+looked up by the device kind the cells were compiled for):
 
-  compute_s    = exec_flops / 197e12            (bf16 MXU peak per chip)
-  memory_s     = exec_bytes / 819e9             (HBM bandwidth per chip)
-  collective_s = Σ_site ring_bytes(site) / 50e9 (ICI per link)
+  compute_s    = exec_flops / flops              (bf16 MXU peak per chip)
+  memory_s     = exec_bytes / hbm_bw             (HBM bandwidth per chip)
+  collective_s = Σ_site ring_bytes(site) / link_bw (ICI per link)
 
 ``exec_*`` are execution-weighted totals from ``repro.launch.hloparse``
 (while bodies × known trip count — raw ``cost_analysis`` counts each body
@@ -33,9 +34,26 @@ import json
 import os
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12   # bf16 / chip
-HBM_BW = 819e9        # bytes/s / chip
-LINK_BW = 50e9        # bytes/s / ICI link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+# 819 GB/s, 1,600 Gbit/s of inter-chip interconnect (four 50 GB/s links).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+# the dry-run compiles its cells for a v5e pod (launch/dryrun.py)
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peak rates of one chip of ``device_kind``; an unknown kind is an
+    error, never a default."""
+    if device_kind not in PEAKS:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})"
+        )
+    return PEAKS[device_kind]
 
 RING_FACTOR = {
     "all-gather": lambda g: (g - 1) / g,
@@ -217,7 +235,7 @@ def model_flops_6nd(cfg, shape: Dict, kind: str) -> float:
 # per-cell roofline
 # ---------------------------------------------------------------------------
 
-def collective_seconds(exec_sum: Dict) -> float:
+def collective_seconds(exec_sum: Dict, link_bw: float) -> float:
     """Ring-model seconds over the per-link bandwidth."""
     sites = exec_sum.get("collective_sites") or []
     if sites:
@@ -225,14 +243,15 @@ def collective_seconds(exec_sum: Dict) -> float:
         for s in sites:
             f = RING_FACTOR.get(s["kind"], lambda g: 1.0)(max(int(s["group"]), 1))
             total += s["bytes"] * s["mult"] * f
-        return total / LINK_BW
+        return total / link_bw
     # fallback: raw sum (no group info)
-    return sum(exec_sum.get("collective_bytes", {}).values()) / LINK_BW
+    return sum(exec_sum.get("collective_bytes", {}).values()) / link_bw
 
 
 def cell_roofline(rec: Dict) -> Optional[Dict]:
     if rec.get("status") != "ok":
         return None
+    peak = peaks(DRYRUN_DEVICE_KIND)
     from repro.configs import SHAPES, get_config
 
     cfg = get_config(rec["arch"])
@@ -240,12 +259,12 @@ def cell_roofline(rec: Dict) -> Optional[Dict]:
     ex = rec["exec"]
     n_dev = rec["n_devices"]
 
-    compute_s = ex["flops"] / PEAK_FLOPS
-    memory_hlo_s = ex["bytes"] / HBM_BW
+    compute_s = ex["flops"] / peak["flops"]
+    memory_hlo_s = ex["bytes"] / peak["hbm_bw"]
     mem = analytic_memory_bytes(cfg, shape, shape["kind"], n_dev)
-    memory_s = mem["total"] / HBM_BW
-    coll_s = collective_seconds(ex)
-    coll_raw_s = sum(ex.get("collective_bytes", {}).values()) / LINK_BW
+    memory_s = mem["total"] / peak["hbm_bw"]
+    coll_s = collective_seconds(ex, peak["link_bw"])
+    coll_raw_s = sum(ex.get("collective_bytes", {}).values()) / peak["link_bw"]
 
     mf = model_flops(cfg, shape, shape["kind"])
     mf6 = model_flops_6nd(cfg, shape, shape["kind"])
@@ -257,7 +276,7 @@ def cell_roofline(rec: Dict) -> Optional[Dict]:
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     bound = max(terms, key=terms.get)
     step_s = max(terms.values())
-    mfu = (mf / (n_dev * PEAK_FLOPS)) / step_s if step_s else 0.0
+    mfu = (mf / (n_dev * peak["flops"])) / step_s if step_s else 0.0
 
     return {
         "arch": rec["arch"],

@@ -27,6 +27,9 @@ def main() -> None:
     quick = not args.full
     skip = set(filter(None, args.skip.split(",")))
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    print(f"# compile cache: {enable_compile_cache()}")
     from benchmarks import graph_throughput, lm_step_bench, serving_bench
 
     if "graph" not in skip:

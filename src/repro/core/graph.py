@@ -135,25 +135,25 @@ class WaitFreeGraph:
         take a sort-free vectorized path; only conflicted ops pay the scans.
 
     ``traversal_impl`` selects the frontier-expansion backend for every
-    traversal query (``None`` = auto: Pallas kernel on TPU, pure-jnp
-    reference elsewhere; ``"kernel"`` / ``"kernel_interpret"`` /
-    ``"reference"`` force one — see :mod:`repro.kernels.frontier`).
+    traversal query (``None`` = the XLA implementation on every backend;
+    ``"kernel_interpret"`` runs the Pallas kernel through the interpreter
+    — the TPU compiler refuses it, see :mod:`repro.kernels.frontier`).
 
     ``csr_maintenance`` picks what happens to a cached traversal snapshot
     when an update batch lands: ``"delta"`` folds the batch into it with
     :func:`repro.core.traversal.apply_delta` (bit-identical to a rebuild,
-    O(batch) instead of O(capacity) — the win for update-light query-heavy
+    no O(capacity) re-probe — the win for update-light query-heavy
     mixes), ``"rebuild"`` discards it and recompacts lazily on next query.
 
     ``maintenance_impl`` selects where table maintenance (growth rehash and
     the ``apply_delta`` splice) runs: ``"device"`` routes both through
-    :mod:`repro.core.maintenance` (the :mod:`repro.kernels.compact`
-    sort + prefix-sum pipeline; a growth rehash also pre-compacts the
-    traversal snapshot so the post-growth ``build_csr`` is one delta fold),
-    ``"device_interpret"`` forces the Pallas kernels through the
-    interpreter, ``"host"`` keeps the vectorized-numpy oracle.  ``None`` =
-    auto: device on TPU, host elsewhere.  All impls produce bit-identical
-    tables, so the flag is purely a performance knob.
+    jitted device passes (:mod:`repro.core.maintenance` over the
+    :mod:`repro.kernels.compact` primitives; a growth rehash also
+    pre-compacts the traversal snapshot so the post-growth ``build_csr`` is
+    one delta fold), ``"device_interpret"`` swaps in the compact Pallas
+    kernels through the interpreter, ``"host"`` keeps the vectorized-numpy
+    oracle.  ``None`` = auto: device on TPU, host elsewhere.  All impls
+    produce bit-identical tables at every size.
 
     ``obs`` enables wait-free telemetry (:mod:`repro.obs`): ``None`` defers
     to the ``REPRO_OBS`` env var, ``True`` attaches a fresh
@@ -797,14 +797,18 @@ class WaitFreeGraph:
 
     def khop(self, u: int, k: int) -> Set[int]:
         """Vertex keys within ≤k directed hops of ``u`` (including ``u``)."""
-        pk, _ = self._pad_keys([u])
+        return self.khop_batch([u], k)[0]
+
+    def khop_batch(self, sources: Sequence[int], k: int) -> List[Set[int]]:
+        """Batched k-hop: one key set per source, all against one snapshot."""
+        pk, n = self._pad_keys(sources)
         csr = self.traversal_csr()
-        self.obs.counter("query.khop")
+        self.obs.counter("query.khop", n)
         mask = np.asarray(
             traversal.khop_mask(csr, pk, np.int32(k), impl=self.traversal_impl)
-        )[0]
+        )[:n]
         v_key = np.asarray(csr.v_key)
-        return {int(v_key[j]) for j in np.nonzero(mask)[0]}
+        return [{int(v_key[j]) for j in np.nonzero(row)[0]} for row in mask]
 
     def get_path(self, u: int, v: int) -> Optional[List[int]]:
         """A shortest directed path ``u ↝ v`` as an explicit key list

@@ -27,31 +27,33 @@ snapshot graphs: three operations sharing one sort + prefix-sum core (the
 
 3. **delta-merge** (:func:`delta_merge`) — the device half of
    :func:`repro.core.traversal.apply_delta`: drop the lanes invalidated by
-   the batch (prefix-sum compaction of the survivors), sort the
-   O(batch)-sized delta, and splice it into the surviving runs with a
-   device-side ``searchsorted`` merge — no host round-trip, no O(valid
-   edges) lexsort.  Bit-identical to a full rebuild by construction.
+   the batch, scatter the survivors and the O(batch)-sized delta back to
+   edge-table lane order, and restore the rebuild's (source slot, lane)
+   order with one stable sort — no host round-trip and no O(capacity)
+   re-probe.  Bit-identical to a full rebuild by construction, at any
+   table size (no composite key has to fit in int32).
 
 Impl selection (the ``maintenance_impl`` flag on ``WaitFreeGraph``):
 
-* ``"host"`` — the numpy oracle (:func:`rehash_host`): vectorized claim
-  rounds with the *identical* discipline, kept as the reference every
-  device path must match bit-exactly, and as the fallback when a device
-  path is unavailable.
-* ``"device"`` — the :mod:`repro.kernels.compact` primitives (Pallas
-  kernel on TPU, pure-jnp reference elsewhere; ``REPRO_COMPACT_IMPL``
-  overrides).
-* ``"device_interpret"`` — the Pallas kernels through the interpreter
-  (CI parity on CPU).
-* ``None`` — auto: ``"device"`` on TPU, ``"host"`` elsewhere (the same
-  per-backend dispatch the kernel families use: XLA CPU lowers the
-  scatter/sort core near-serially, so the host oracle wins there).
+* ``"host"`` — the numpy oracle (:func:`rehash_host`, and the numpy splice
+  of ``apply_delta``): vectorized claim rounds with the *identical*
+  discipline, kept as the reference every device path must match
+  bit-exactly.
+* ``"device"`` — jitted device passes over the :mod:`repro.kernels.compact`
+  primitives (their XLA implementation on every backend;
+  ``REPRO_COMPACT_IMPL`` overrides).
+* ``"device_interpret"`` — the same, with the compact Pallas kernels run
+  through the interpreter (CI parity on CPU; the TPU compiler refuses
+  them, see ``docs/KERNELS.md``).
+* ``None`` — auto: ``"device"`` on TPU, ``"host"`` elsewhere (XLA CPU
+  lowers the scatter/sort core near-serially, so the host oracle wins
+  there).
 
 All impls produce bit-identical tables: placement is priority-ordered
 claim rounds (lowest compaction index wins each contended slot), which is
 deterministic and order-independent of how the rounds are vectorized —
-see ``repro.kernels.compact.ref`` and ``docs/KERNELS.md`` (the shared
-``kernel/ops/ref`` contract and the ``probe_place`` VMEM limit).
+see ``repro.kernels.compact.xla`` and ``docs/KERNELS.md`` (the shared
+``kernel/ops/xla`` contract).
 
 **Linearization point** (mirroring the paper's growth argument): *a rehash
 linearizes at the batch boundary that triggered it — the caller discards
@@ -84,20 +86,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.compact import masked_compact, probe_place
-from repro.kernels.compact.ops import _resolve as _resolve_compact_impl
+from repro.kernels.compact.ops import resolve as _resolve_compact_impl
 
 # ambient telemetry (no-op unless a registry is active — see repro.obs;
 # metrics imports nothing from repro.core, so this is cycle-free)
 from ..obs import metrics as obsm
 from .hashing import edge_hash32_np, hash_edge, hash_vertex, vertex_hash32_np
-from .traversal import TraversalCSR, _delta_probe_parts, _edge_validity, build_csr
+from .traversal import (
+    TraversalCSR,
+    _delta_probe_parts,
+    _edge_validity,
+    build_csr,
+    csr_from_lanes,
+)
 from .types import ABSENT_INC, EMPTY_KEY, MAX_PROBES, GraphState
 
 MAINTENANCE_IMPLS = (None, "host", "device", "device_interpret")
-
-# Composite (src, lane) merge keys must fit int32 (x64 stays disabled);
-# beyond this the delta fold falls back to the host splice.
-_MERGE_KEY_LIMIT = 2**31
 
 
 def resolve_impl(impl: Optional[str]) -> str:
@@ -387,22 +391,7 @@ def _rehash_device(
     dst_lane = jnp.full(new_ecap, new_vcap, i32).at[we].set(
         old2new[safe_sv], mode="drop"
     )
-    csr_order = jnp.argsort(src_lane, stable=True).astype(i32)
-    src_sorted = src_lane[csr_order]
-    dst_sorted = dst_lane[csr_order]
-    rows = jnp.arange(new_vcap, dtype=i32)
-    csr = TraversalCSR(
-        v_key=n_vkey,
-        v_live=n_vlive,
-        v_inc=n_vinc,
-        n_live=n_v,
-        src=src_sorted,
-        dst=dst_sorted,
-        lane=csr_order,
-        row_start=jnp.searchsorted(src_sorted, rows, side="left").astype(i32),
-        row_end=jnp.searchsorted(src_sorted, rows, side="right").astype(i32),
-        n_edges=n_e,
-    )
+    csr = csr_from_lanes(new_state, src_lane, dst_lane, n_v, n_e)
     return new_state, csr, ok
 
 
@@ -461,29 +450,20 @@ def rehash(
 
 
 # ---------------------------------------------------------------------------
-# device delta-merge (the searchsorted splice of apply_delta)
+# device delta-merge (the device splice of apply_delta)
 # ---------------------------------------------------------------------------
 
 
-def merge_keys_fit(cv: int, ce: int) -> bool:
-    """Whether composite (src, lane) merge keys fit int32 for these
-    capacities (the device merge's applicability guard)."""
-    return cv * ce < _MERGE_KEY_LIMIT
-
-
-@functools.partial(jax.jit, static_argnames=("nv", "ne", "prim"))
+@functools.partial(jax.jit, static_argnames=("nv", "ne"))
 def _delta_merge_device(
     csr: TraversalCSR,
     state: GraphState,
     pack: jnp.ndarray,
     nv: int,
     ne: int,
-    prim: str,
 ):
-    i32 = jnp.int32
     cv = csr.v_capacity
     ce = csr.e_capacity
-    big = jnp.iinfo(jnp.int32).max
     p = _delta_probe_parts(state, pack[:nv], pack[nv:nv + ne], pack[nv + ne:])
 
     # vertices whose (live, inc) changed invalidate every lane bound to them
@@ -495,71 +475,24 @@ def _delta_merge_device(
     hit = hit.at[jnp.where(changed, p.v_slot, cv + 1)].set(True, mode="drop")
 
     # every touched edge key is re-derived from the post state: drop its old
-    # entry (if any) so the merge below is the single source
+    # entry (if any) so the delta below is the single source
     ltouch = jnp.zeros(ce, bool)
     ltouch = ltouch.at[jnp.where(p.e_found, p.e_lane, ce)].set(True, mode="drop")
 
-    in_prefix = jnp.arange(ce, dtype=i32) < csr.n_edges
+    in_prefix = jnp.arange(ce, dtype=jnp.int32) < csr.n_edges
     keep = in_prefix & ~(hit[csr.src] | hit[csr.dst]) & ~ltouch[csr.lane]
-    svals = jnp.stack([csr.src, csr.dst, csr.lane])
-    scomp, n_keep = masked_compact(svals, keep, fill=0, impl=prim)
-    s_src, s_dst, s_lane = scomp
-    s_active = jnp.arange(ce, dtype=i32) < n_keep
-    s_key = jnp.where(s_active, s_src * ce + s_lane, big)
-
-    # the O(batch) delta, sorted by the same (src, lane) order the rebuild's
-    # stable argsort produces
     ins = p.e_found & p.e_valid
-    d_key0 = jnp.where(ins, p.e_su * ce + p.e_lane, big)
-    dorder = jnp.argsort(d_key0, stable=True)
-    d_key = d_key0[dorder]
-    d_src = p.e_su[dorder]
-    d_dst = p.e_sv[dorder]
-    d_lane = p.e_lane[dorder]
-    d_ins = ins[dorder]
-    n_ins = jnp.sum(ins).astype(i32)
 
-    # searchsorted merge: keys are distinct (lanes are), so each side's final
-    # position is its own rank plus the other side's count of smaller keys
-    pos_s = jnp.arange(ce, dtype=i32) + jnp.searchsorted(d_key, s_key).astype(i32)
-    pos_s = jnp.where(s_active, pos_s, ce)
-    pos_d = (
-        jnp.arange(d_key.shape[0], dtype=i32)
-        + jnp.searchsorted(s_key, d_key).astype(i32)
-    )
-    pos_d = jnp.where(d_ins, pos_d, ce)
-
-    out_src = jnp.full(ce, cv, i32).at[pos_s].set(s_src, mode="drop")
-    out_src = out_src.at[pos_d].set(d_src, mode="drop")
-    out_dst = jnp.full(ce, cv, i32).at[pos_s].set(s_dst, mode="drop")
-    out_dst = out_dst.at[pos_d].set(d_dst, mode="drop")
-    out_lane = jnp.zeros(ce, i32).at[pos_s].set(s_lane, mode="drop")
-    out_lane = out_lane.at[pos_d].set(d_lane, mode="drop")
-
-    # tail: the unused lanes in ascending order, exactly where the rebuild's
-    # stable argsort leaves the invalid lanes
-    n_valid = n_keep + n_ins
-    lane_used = jnp.zeros(ce, bool)
-    lane_used = lane_used.at[jnp.where(s_active, s_lane, ce)].set(True, mode="drop")
-    lane_used = lane_used.at[jnp.where(d_ins, d_lane, ce)].set(True, mode="drop")
-    lanes = jnp.arange(ce, dtype=i32)
-    ucomp, n_unused = masked_compact(lanes[None, :], ~lane_used, fill=0, impl=prim)
-    tail_pos = jnp.where(lanes < n_unused, n_valid + lanes, ce)
-    out_lane = out_lane.at[tail_pos].set(ucomp[0], mode="drop")
-
-    rows = jnp.arange(cv, dtype=i32)
-    return TraversalCSR(
-        v_key=state.v_key,
-        v_live=state.v_live,
-        v_inc=state.v_inc,
-        n_live=p.n_live,
-        src=out_src,
-        dst=out_dst,
-        lane=out_lane,
-        row_start=jnp.searchsorted(out_src, rows, side="left").astype(i32),
-        row_end=jnp.searchsorted(out_src, rows, side="right").astype(i32),
-        n_edges=n_valid,
-    )
+    # post-batch endpoint slots of every edge-table lane (Cv = invalid): the
+    # surviving entries scatter back to their lanes, the delta on top
+    kl = jnp.where(keep, csr.lane, ce)
+    dl = jnp.where(ins, p.e_lane, ce)
+    src_lane = jnp.full(ce, cv, jnp.int32).at[kl].set(csr.src, mode="drop")
+    dst_lane = jnp.full(ce, cv, jnp.int32).at[kl].set(csr.dst, mode="drop")
+    src_lane = src_lane.at[dl].set(p.e_su, mode="drop")
+    dst_lane = dst_lane.at[dl].set(p.e_sv, mode="drop")
+    n_edges = (jnp.sum(keep) + jnp.sum(ins)).astype(jnp.int32)
+    return csr_from_lanes(state, src_lane, dst_lane, p.n_live, n_edges)
 
 
 def delta_merge(
@@ -568,13 +501,14 @@ def delta_merge(
     pack: np.ndarray,
     nv: int,
     ne: int,
-    *,
-    impl: Optional[str] = None,
 ) -> TraversalCSR:
     """Fold the (deduplicated, bucket-padded, packed ``vkeys | e_us | e_vs``)
-    touched keys into ``csr`` entirely on device — the searchsorted splice of
+    touched keys into ``csr`` entirely on device — the device splice of
     :func:`repro.core.traversal.apply_delta`, one host-to-device transfer and
     zero device-to-host ones.  Callers are responsible for the fallback
-    guards (capacity change, delta footprint, :func:`merge_keys_fit`);
-    bit-identity to ``build_csr(state)`` holds by construction."""
-    return _delta_merge_device(csr, state, pack, nv, ne, _primitive_impl(impl))
+    guards (capacity change, delta footprint); bit-identity to
+    ``build_csr(state)`` holds by construction: the surviving entries and the
+    delta are scattered back to edge-table lane order and sorted by the
+    rebuild's own two-key order (source slot, then lane — a stable sort of
+    lane order), so no composite key has to fit in int32."""
+    return _delta_merge_device(csr, state, pack, nv, ne)

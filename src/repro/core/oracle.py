@@ -30,11 +30,27 @@ from .types import (
 
 
 class SequentialGraph:
-    """Reference implementation: a plain sequential directed graph."""
+    """Reference implementation: a plain sequential directed graph.
+
+    Edges are kept as out- and in-adjacency sets, so every operation is O(1)
+    except ``remove_vertex`` (O(degree)), and a BFS visits each edge once:
+    the oracle replays a graph of millions of edges in seconds."""
 
     def __init__(self) -> None:
         self.vertices: Set[int] = set()
-        self.edges: Set[Tuple[int, int]] = set()
+        self._out: Dict[int, Set[int]] = {}
+        self._inn: Dict[int, Set[int]] = {}
+
+    @property
+    def edges(self) -> Set[Tuple[int, int]]:
+        return {(a, b) for a, bs in self._out.items() for b in bs}
+
+    @edges.setter
+    def edges(self, value: Iterable[Tuple[int, int]]) -> None:
+        self._out, self._inn = {}, {}
+        for a, b in value:
+            self._out.setdefault(a, set()).add(b)
+            self._inn.setdefault(b, set()).add(a)
 
     # -- the six operations (paper §2.1) --------------------------------
     def add_vertex(self, u: int) -> bool:
@@ -47,32 +63,40 @@ class SequentialGraph:
         if u not in self.vertices:
             return False
         self.vertices.discard(u)
-        self.edges = {(a, b) for (a, b) in self.edges if a != u and b != u}
+        for b in self._out.pop(u, ()):
+            self._inn[b].discard(u)
+        for a in self._inn.pop(u, ()):
+            self._out[a].discard(u)
         return True
 
     def contains_vertex(self, u: int) -> bool:
         return u in self.vertices
 
+    def _has_edge(self, u: int, v: int) -> bool:
+        return v in self._out.get(u, ())
+
     def add_edge(self, u: int, v: int) -> bool:
         if u not in self.vertices or v not in self.vertices:
             return False
-        if (u, v) in self.edges:
+        if self._has_edge(u, v):
             return False
-        self.edges.add((u, v))
+        self._out.setdefault(u, set()).add(v)
+        self._inn.setdefault(v, set()).add(u)
         return True
 
     def remove_edge(self, u: int, v: int) -> bool:
         if u not in self.vertices or v not in self.vertices:
             return False
-        if (u, v) not in self.edges:
+        if not self._has_edge(u, v):
             return False
-        self.edges.discard((u, v))
+        self._out[u].discard(v)
+        self._inn[v].discard(u)
         return True
 
     def contains_edge(self, u: int, v: int) -> bool:
         if u not in self.vertices or v not in self.vertices:
             return False
-        return (u, v) in self.edges
+        return self._has_edge(u, v)
 
     # -- traversal queries (sequential specification) --------------------
     def bfs(self, u: int) -> Dict[int, int]:
@@ -80,17 +104,17 @@ class SequentialGraph:
         Empty when u is absent — matching the engine's dead-source rows."""
         if u not in self.vertices:
             return {}
-        adj: Dict[int, List[int]] = {}
-        for a, b in self.edges:
-            adj.setdefault(a, []).append(b)
         levels = {u: 0}
-        q = deque([u])
-        while q:
-            a = q.popleft()
-            for b in adj.get(a, ()):
-                if b not in levels:
-                    levels[b] = levels[a] + 1
-                    q.append(b)
+        frontier = {u}
+        depth = 0
+        while frontier:
+            depth += 1
+            reached: Set[int] = set()
+            for a in frontier:
+                reached.update(self._out.get(a, ()))
+            frontier = {b for b in reached if b not in levels}
+            for b in frontier:
+                levels[b] = depth
         return levels
 
     def reachable(self, u: int, v: int) -> bool:
@@ -111,14 +135,11 @@ class SequentialGraph:
         (the engine's deterministic min-parent choice need not match)."""
         if u not in self.vertices or v not in self.vertices:
             return None
-        adj: Dict[int, List[int]] = {}
-        for a, b in self.edges:
-            adj.setdefault(a, []).append(b)
         parent = {u: u}
         q = deque([u])
         while q and v not in parent:
             a = q.popleft()
-            for b in adj.get(a, ()):
+            for b in self._out.get(a, ()):
                 if b not in parent:
                     parent[b] = a
                     q.append(b)

@@ -34,8 +34,8 @@ run on top.  This module is the dataflow analogue of their wait-free
    source slot into edge destinations — so the same pass yields both the
    new frontier (hit iff min proposer < NBR_INF) and the BFS *parent* of
    every newly reached slot (the papers' ``GetPath`` pointer).  ``impl``
-   selects the Pallas kernel, its interpret-mode twin, or the pure-jnp
-   reference; all three are bit-identical.  The iteration count is bounded
+   selects the XLA implementation (the default on every backend) or the
+   Pallas kernel in interpret mode; the two are bit-identical.  The iteration count is bounded
    by the live vertex count (no path is longer), so the loop is
    bounded-depth — the traversal analogue of the engines' wait-free locate
    bound — and an edge-free snapshot skips the loop entirely.
@@ -76,6 +76,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.frontier import NBR_INF, frontier_expand
+from repro.kernels.frontier.xla import edge_blocks
 
 # ambient telemetry (no-op unless a registry is active — see repro.obs and
 # docs/OBSERVABILITY.md; metrics imports nothing from repro.core)
@@ -150,34 +151,48 @@ def _edge_validity(state: GraphState):
     return su, sv, valid
 
 
+def csr_from_lanes(
+    state: GraphState,
+    src_lane: jnp.ndarray,
+    dst_lane: jnp.ndarray,
+    n_live: jnp.ndarray,
+    n_edges: jnp.ndarray,
+) -> TraversalCSR:
+    """Sort per-lane endpoint slots (``Cv`` marks an invalid lane) into CSR
+    form.  The order is two keys, source slot then edge-table lane: a stable
+    sort of lane order by source.  Every snapshot path (rebuild, device delta
+    fold, snapshot-compact) ends here, which is what makes them
+    bit-identical."""
+    cv = state.v_key.shape[0]
+    order = jnp.argsort(src_lane, stable=True).astype(jnp.int32)
+    src = src_lane[order]
+    rows = jnp.arange(cv, dtype=jnp.int32)
+    return TraversalCSR(
+        v_key=state.v_key,
+        v_live=state.v_live,
+        v_inc=state.v_inc,
+        n_live=n_live,
+        src=src,
+        dst=dst_lane[order],
+        lane=order,
+        row_start=jnp.searchsorted(src, rows, side="left").astype(jnp.int32),
+        row_end=jnp.searchsorted(src, rows, side="right").astype(jnp.int32),
+        n_edges=n_edges,
+    )
+
+
 @jax.jit
 def build_csr(state: GraphState) -> TraversalCSR:
     """Compact the live, incarnation-valid edge set into CSR form
     (validity per :func:`_edge_validity`)."""
     cv = state.v_key.shape[0]
     su, sv, valid = _edge_validity(state)
-
-    src = jnp.where(valid, su, cv).astype(jnp.int32)
-    dst = jnp.where(valid, sv, cv).astype(jnp.int32)
-    order = jnp.argsort(src, stable=True).astype(jnp.int32)
-    src = src[order]
-    dst = dst[order]
-
-    rows = jnp.arange(cv, dtype=jnp.int32)
-    row_start = jnp.searchsorted(src, rows, side="left").astype(jnp.int32)
-    row_end = jnp.searchsorted(src, rows, side="right").astype(jnp.int32)
-
-    return TraversalCSR(
-        v_key=state.v_key,
-        v_live=state.v_live,
-        v_inc=state.v_inc,
-        n_live=jnp.sum(state.v_live).astype(jnp.int32),
-        src=src,
-        dst=dst,
-        lane=order,
-        row_start=row_start,
-        row_end=row_end,
-        n_edges=jnp.sum(valid).astype(jnp.int32),
+    return csr_from_lanes(
+        state,
+        jnp.where(valid, su, cv).astype(jnp.int32),
+        jnp.where(valid, sv, cv).astype(jnp.int32),
+        jnp.sum(state.v_live).astype(jnp.int32),
+        jnp.sum(valid).astype(jnp.int32),
     )
 
 
@@ -316,17 +331,16 @@ def apply_delta(
     elsewhere — ``maintenance.resolve_impl``):
 
     * ``"device"`` / ``"device_interpret"`` — the whole fold is one fused
-      jitted pass (:func:`repro.core.maintenance.delta_merge`): prefix-sum
-      compaction of the surviving lanes, a sort of the O(batch) delta
-      (bucketed shapes, so it compiles once per bucket), and a device-side
-      ``searchsorted`` merge into the surviving runs.  One host-to-device
-      transfer (the packed touched keys), zero transfers back — the host
-      lexsort round-trip this path replaces was the dominant refresh cost.
+      jitted pass (:func:`repro.core.maintenance.delta_merge`): the O(batch)
+      touched keys are re-probed, the surviving entries and the delta are
+      scattered back to lane order, and one stable sort by source slot
+      restores the rebuild's (source, lane) order — no O(capacity) re-probe
+      of the table.  One host-to-device transfer (the packed touched keys),
+      zero transfers back.
     * ``"host"`` — the numpy splice: mask updates and a lexsort over the
       surviving lanes on the host (O(valid edges) with small vectorized
       constants).  Kept as the oracle the device merge is tested
-      bit-identical against, and as the fallback when the composite merge
-      keys would overflow int32 (``maintenance.merge_keys_fit``).
+      bit-identical against.
 
     Falls back to :func:`build_csr` automatically when
 
@@ -373,16 +387,9 @@ def apply_delta(
     from . import maintenance  # deferred: maintenance imports this module
 
     if maintenance.resolve_impl(impl) != "host":
-        if maintenance.merge_keys_fit(csr.v_capacity, ce):
-            return maintenance.delta_merge(
-                csr,
-                state,
-                np.concatenate([v_pad, eu_pad, ev_pad]),
-                nvp,
-                nep,
-                impl=impl,
-            )
-        # composite merge keys would overflow int32: host splice below
+        return maintenance.delta_merge(
+            csr, state, np.concatenate([v_pad, eu_pad, ev_pad]), nvp, nep
+        )
 
     packed, n_live = _delta_probe(
         state, np.concatenate([v_pad, eu_pad, ev_pad]), nvp, nep
@@ -478,10 +485,17 @@ def _locate_live_slots(csr: TraversalCSR, keys: jnp.ndarray):
     return slot, live
 
 
-def _bfs_from_slots(csr: TraversalCSR, slot: jnp.ndarray, live: jnp.ndarray, impl: Optional[str]):
+def _bfs_from_slots(
+    csr: TraversalCSR,
+    slot: jnp.ndarray,
+    live: jnp.ndarray,
+    impl: Optional[str],
+    max_depth: Optional[jnp.ndarray] = None,
+):
     """The frontier loop, from already-located source slots (callers resolve
     each endpoint set exactly once — see :func:`reachable`).  Returns
     (levels, parents): i32[S, Cv] each, -1 for unreached / no parent.
+    ``max_depth`` stops the expansion after that many levels (k-hop).
 
     One :func:`frontier_expand` per level: the scatter-min result is both
     the discovery mask (min < NBR_INF) and the parent pointer of every
@@ -497,10 +511,11 @@ def _bfs_from_slots(csr: TraversalCSR, slot: jnp.ndarray, live: jnp.ndarray, imp
     levels = jnp.full((n_src, cv + 1), _NO_LEVEL)
     levels = jnp.where(frontier, 0, levels)
     parents = jnp.full((n_src, cv + 1), _NO_PARENT)
+    bound = csr.n_live if max_depth is None else jnp.minimum(csr.n_live, max_depth)
 
     def cond(carry):
         _, _, frontier, depth = carry
-        return jnp.any(frontier[:, :cv]) & (depth < csr.n_live)
+        return jnp.any(frontier[:, :cv]) & (depth < bound)
 
     def body(carry):
         levels, parents, frontier, depth = carry
@@ -529,7 +544,7 @@ def bfs_parents(csr: TraversalCSR, src_keys: jnp.ndarray, impl: Optional[str] = 
     in slot ``j`` (0 for the source itself, -1 unreachable); ``parents[s, j]``
     is the slot the BFS reached ``j`` from (-1 for sources and unreached
     slots).  Parents are deterministic: the minimum frontier source slot
-    among ``j``'s in-edges, identical across kernel/reference impls.
+    among ``j``'s in-edges, identical across the XLA and kernel impls.
     """
     slot, live = _locate_live_slots(csr, src_keys)
     return _bfs_from_slots(csr, slot, live, impl)
@@ -583,15 +598,27 @@ def _canonical_parents(csr: TraversalCSR, levels: jnp.ndarray) -> jnp.ndarray:
     order = jnp.argsort(jnp.where(csr.v_live, csr.v_key, big)).astype(i32)
     rank = jnp.zeros(cv, i32).at[order].set(jnp.arange(cv, dtype=i32))
 
-    # sentinel column cv absorbs invalid edge lanes (src == dst == cv)
-    lv = jnp.concatenate([levels, jnp.full((n_src, 1), _NO_LEVEL)], axis=1)
-    ls = lv[:, csr.src]
-    ld = lv[:, csr.dst]
-    on_path = (ls >= 0) & (ld == ls + 1)
-    cand = jnp.where(on_path, rank[jnp.clip(csr.src, 0, cv - 1)], big)
-    best = jnp.full((n_src, cv + 1), big, i32)
-    best = best.at[jnp.arange(n_src, dtype=i32)[:, None], csr.dst[None, :]].min(cand)
-    best = best[:, :cv]
+    # transposed [Cv+1, S] level map, streamed over edge blocks like the
+    # frontier expansion (:mod:`repro.kernels.frontier.xla`); sentinel row
+    # cv absorbs invalid edge lanes (src == dst == cv) and block padding
+    lt = jnp.concatenate([levels.T, jnp.full((1, n_src), _NO_LEVEL)], axis=0)
+    rank = jnp.concatenate([rank, jnp.full((1,), big, i32)])
+    n_edges = csr.src.shape[0]
+    block, n_blocks = edge_blocks(n_edges, n_src)
+    pad = jnp.full((n_blocks * block - n_edges,), cv, i32)
+    src = jnp.concatenate([csr.src, pad])
+    dst = jnp.concatenate([csr.dst, pad])
+
+    def body(i, best):
+        s = jax.lax.dynamic_slice(src, (i * block,), (block,))
+        d = jax.lax.dynamic_slice(dst, (i * block,), (block,))
+        ls, ld = lt[s], lt[d]
+        on_path = (ls >= 0) & (ld == ls + 1)
+        return best.at[d].min(jnp.where(on_path, rank[s][:, None], big))
+
+    best = jax.lax.fori_loop(
+        0, n_blocks, body, jnp.full((cv + 1, n_src), big, i32)
+    )[:cv].T
     parent = jnp.where(
         (best < big) & (levels > 0), order[jnp.clip(best, 0, cv - 1)], _NO_PARENT
     )
@@ -620,9 +647,12 @@ def path_probe(
 def khop_mask(
     csr: TraversalCSR, src_keys: jnp.ndarray, k: jnp.ndarray, impl: Optional[str] = None
 ) -> jnp.ndarray:
-    """bool[S, Cv]: slots within ≤k directed hops of each source (incl. self)."""
-    levels = bfs_levels(csr, src_keys, impl=impl)
-    return (levels >= 0) & (levels <= jnp.asarray(k, jnp.int32))
+    """bool[S, Cv]: slots within ≤k directed hops of each source (incl. self);
+    the expansion stops after k levels."""
+    slot, live = _locate_live_slots(csr, src_keys)
+    k = jnp.asarray(k, jnp.int32)
+    levels, _ = _bfs_from_slots(csr, slot, live, impl, max_depth=k)
+    return (levels >= 0) & (levels <= k)
 
 
 @jax.jit

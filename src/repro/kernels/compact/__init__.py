@@ -1,10 +1,10 @@
 from .ops import masked_compact, probe_place
-from .ref import masked_compact_reference, probe_place_reference, probe_place_rounds
+from .xla import masked_compact_xla, probe_place_rounds, probe_place_xla
 
 __all__ = [
     "masked_compact",
     "probe_place",
-    "masked_compact_reference",
-    "probe_place_reference",
+    "masked_compact_xla",
+    "probe_place_xla",
     "probe_place_rounds",
 ]

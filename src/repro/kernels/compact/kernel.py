@@ -8,21 +8,21 @@ Hardware adaptation (same playbook as ``hash_probe`` and ``frontier``):
   resident; a running offset carried in the ``count`` output turns each
   chunk's local ``cumsum`` into global scatter positions.  Compaction is
   order-preserving, so the chunked result is bit-identical to the one-shot
-  jnp reference.
+  XLA path.
 
 * ``probe_place`` — vectorized quadratic-probe placement.  The occupancy
   bitmap and the claim column live on-chip for the whole round loop (the
   same residency argument as ``hash_probe`` keeping the key column in
   VMEM: a 2²⁰-slot occupancy map is 1 MiB), and each round is one
   vectorized gather (first-empty probe) plus one scatter-min (claim).  The
-  round loop itself is :func:`repro.kernels.compact.ref.probe_place_rounds`
-  — shared verbatim with the pure-jnp reference, so kernel and reference
+  round loop itself is :func:`repro.kernels.compact.xla.probe_place_rounds`
+  — shared verbatim with the XLA path, so kernel and XLA path
   are bit-identical by construction.
 
 The ``interpret=True`` path runs the identical kernels through the Pallas
-interpreter; CI forces it on CPU (the ``kernels-interpret`` job).  On-TPU
-validation of the compiled path rides the same ROADMAP follow-up as the
-frontier kernel.
+interpreter; CI forces it on CPU (the ``kernels-interpret`` job).  The v5e
+compiler refuses both kernels (no ``cumsum`` and no 1-D gather in Mosaic;
+``docs/KERNELS.md``), so the TPU dispatch never selects them.
 """
 
 from __future__ import annotations
@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .ref import probe_place_rounds
+from .xla import probe_place_rounds
 
 
 def _compact_kernel(values_ref, mask_ref, out_ref, count_ref, *, n_pad: int, fill: int):
@@ -66,7 +66,7 @@ def masked_compact(
     block_n: int = 1024,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(out i32[R, N], count i32[]) — see the reference for the contract."""
+    """(out i32[R, N], count i32[]) — see the XLA path for the contract."""
     r, n = values.shape
     block_n = min(block_n, max(n, 1))
     n_pad = _round_up(max(n, 1), block_n)
@@ -111,7 +111,7 @@ def probe_place(
     max_probes: int,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(slots i32[m], overflow bool[]) — see the reference for the contract."""
+    """(slots i32[m], overflow bool[]) — see the XLA path for the contract."""
     m = home.shape[0]
     kernel = functools.partial(_place_kernel, capacity=capacity, max_probes=max_probes)
     slots, over = pl.pallas_call(

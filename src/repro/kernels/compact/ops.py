@@ -1,34 +1,28 @@
 """Public entry points for the compaction primitives.
 
-Dispatch mirrors ``repro.kernels.frontier``: the Pallas kernel on TPU, the
-pure-jnp reference elsewhere.  ``REPRO_COMPACT_IMPL`` overrides the default
-(CI's ``kernels-interpret`` job sets it to ``kernel_interpret`` so the
-interpreter path is forced on CPU).  All impls are bit-identical; callers
-that need a *host* (numpy) oracle use ``repro.core.maintenance`` instead.
-
-The full ``kernel/ops/ref`` contract — and the ``probe_place`` VMEM limit
-(single-block occupancy map, ~2**22 slots) that hash-prefix sharding
-side-steps by keeping per-shard tables small — is documented once in
-``docs/KERNELS.md``.
+Dispatch is a fixed rule, as for ``repro.kernels.frontier``: every backend
+runs the XLA implementation (:mod:`.xla`).  The TPU compiler refuses both
+Pallas kernels (:mod:`.kernel`) — Mosaic lowers no ``cumsum`` and no 1-D
+gather; the compiler's words are in ``docs/KERNELS.md`` — so they run only
+through the Pallas interpreter, where the tests hold them bit-identical to
+the XLA path.  ``REPRO_COMPACT_IMPL`` overrides the default (CI's
+``kernels-interpret`` job sets it to ``kernel_interpret``).  Callers that
+need a *host* (numpy) oracle use ``repro.core.maintenance`` instead.
 """
 
 from __future__ import annotations
 
 import os
 
-import jax
 import jax.numpy as jnp
 
 from . import kernel as _kernel
-from . import ref as _ref
+from . import xla as _xla
 
 
-def _resolve(impl: str | None) -> str:
-    return (
-        impl
-        or os.environ.get("REPRO_COMPACT_IMPL")
-        or ("kernel" if jax.default_backend() == "tpu" else "reference")
-    )
+def resolve(impl: str | None = None) -> str:
+    """The implementation a call with ``impl`` runs."""
+    return impl or os.environ.get("REPRO_COMPACT_IMPL") or "xla"
 
 
 def masked_compact(
@@ -38,13 +32,11 @@ def masked_compact(
     fill: int,
     impl: str | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    impl = _resolve(impl)
-    if impl == "kernel":
-        return _kernel.masked_compact(values, mask, fill=fill)
+    impl = resolve(impl)
+    if impl == "xla":
+        return _xla.masked_compact_xla(values, mask, fill=fill)
     if impl == "kernel_interpret":
         return _kernel.masked_compact(values, mask, fill=fill, interpret=True)
-    if impl == "reference":
-        return _ref.masked_compact_reference(values, mask, fill=fill)
     raise ValueError(f"unknown impl {impl!r}")
 
 
@@ -56,15 +48,11 @@ def probe_place(
     max_probes: int,
     impl: str | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    impl = _resolve(impl)
-    if impl == "kernel":
-        return _kernel.probe_place(home, active, capacity=capacity, max_probes=max_probes)
+    impl = resolve(impl)
+    if impl == "xla":
+        return _xla.probe_place_xla(home, active, capacity=capacity, max_probes=max_probes)
     if impl == "kernel_interpret":
         return _kernel.probe_place(
             home, active, capacity=capacity, max_probes=max_probes, interpret=True
-        )
-    if impl == "reference":
-        return _ref.probe_place_reference(
-            home, active, capacity=capacity, max_probes=max_probes
         )
     raise ValueError(f"unknown impl {impl!r}")

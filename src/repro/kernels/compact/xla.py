@@ -1,15 +1,15 @@
-"""Pure-jnp references for the state-maintenance compaction primitives.
+"""XLA (pure-jnp) compaction primitives — the implementation every backend runs.
 
 The maintenance subsystem (``repro.core.maintenance``) is built from two
 primitives that share one sort + prefix-sum core:
 
-* :func:`masked_compact_reference` — stable stream compaction: keep the
+* :func:`masked_compact_xla` — stable stream compaction: keep the
   columns of ``values`` whose ``mask`` lane is set, in order, and push the
   rest off the end.  One ``cumsum`` (the prefix sum) turns the mask into
   scatter positions; the result is order-preserving, so every impl of it is
   bit-identical by construction.
 
-* :func:`probe_place_reference` — vectorized quadratic-probe placement:
+* :func:`probe_place_xla` — vectorized quadratic-probe placement:
   insert a set of distinct pre-hashed keys into an empty power-of-two
   table.  The discipline is *priority-ordered claim rounds*, the same one
   :func:`repro.core.locate._claim_slots` uses for engine inserts: every
@@ -19,7 +19,7 @@ primitives that share one sort + prefix-sum core:
   so every round places at least one key and the loop is bounded by the
   lane count — placement is wait-free in the same sense as the engines'
   bounded locate.  The round/claim order is fully deterministic, which is
-  what lets the host oracle (``maintenance.rehash_host``), this reference,
+  what lets the host oracle (``maintenance.rehash_host``), this module,
   and the Pallas kernel produce bit-identical tables.
 
 Placement is bounded by ``max_probes`` — callers pass ``MAX_PROBES`` so a
@@ -46,7 +46,7 @@ def _probe_slot(home: jnp.ndarray, step: jnp.ndarray, capacity: int) -> jnp.ndar
     return (home + off) & (capacity - 1)
 
 
-def masked_compact_reference(
+def masked_compact_xla(
     values: jnp.ndarray,  # i32[R, N] — R payload rows sharing one mask
     mask: jnp.ndarray,    # bool[N]
     *,
@@ -69,7 +69,7 @@ def probe_place_rounds(
     capacity: int,
     max_probes: int,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The claim-round loop on values — shared verbatim by the reference and
+    """The claim-round loop on values — shared verbatim by the XLA path and
     the Pallas kernel (which runs it on VMEM-resident blocks), so the two
     are bit-identical by construction.  Returns (slots i32[m], overflow
     bool[]); ``slots[i] == -1`` where inactive or unplaced."""
@@ -110,12 +110,12 @@ def probe_place_rounds(
     return slots, jnp.any(pending)
 
 
-def probe_place_reference(
+def probe_place_xla(
     home: jnp.ndarray,
     active: jnp.ndarray,
     *,
     capacity: int,
     max_probes: int,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Pure-jnp placement: see :func:`probe_place_rounds`."""
+    """XLA placement: see :func:`probe_place_rounds`."""
     return probe_place_rounds(home, active, capacity=capacity, max_probes=max_probes)

@@ -1,4 +1,4 @@
 from .ops import frontier_expand
-from .ref import NBR_INF, frontier_expand_reference
+from .xla import NBR_INF, frontier_expand_xla
 
-__all__ = ["frontier_expand", "frontier_expand_reference", "NBR_INF"]
+__all__ = ["frontier_expand", "frontier_expand_xla", "NBR_INF"]

@@ -15,14 +15,15 @@ the gather hit, and fold the proposals into the output block with a
 scatter-min.  The output block is revisited across the edge-tile axis
 (initialised to NBR_INF at j == 0), so the full reduction over all edges
 lands without ever leaving VMEM.  Min is associative and commutative, so
-the tiled reduction is bit-identical to the pure-jnp reference regardless
+the tiled reduction is bit-identical to the XLA implementation regardless
 of edge order — which is what lets one scatter serve both frontier
 discovery (hit iff result < NBR_INF) and the papers' ``GetPath`` parent
 pointers (the result *is* the parent slot).
 
 The ``interpret=True`` path runs the identical kernel through the Pallas
-interpreter, so CPU CI exercises the same code the TPU compiles (see
-``tests/test_frontier_kernel.py`` and the ``kernels-interpret`` CI job).
+interpreter (``tests/test_frontier_kernel.py`` and the ``kernels-interpret``
+CI job).  The v5e compiler refuses the kernel (Mosaic lowers no scatter and
+no 1-D gather; ``docs/KERNELS.md``), so the TPU dispatch never selects it.
 """
 
 from __future__ import annotations
@@ -33,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .ref import NBR_INF
+from .xla import NBR_INF
 
 _LANE = 128  # TPU lane width: last-dim blocks are padded to multiples of this
 

@@ -1,22 +1,27 @@
 """Public entry point for the batched frontier expansion.
 
-Dispatch mirrors ``repro.kernels.hash_probe``: the Pallas kernel on TPU,
-the pure-jnp reference elsewhere.  ``REPRO_FRONTIER_IMPL`` overrides the
-default (CI's ``kernels-interpret`` job sets it to ``kernel_interpret`` so
-the interpreter path is forced on CPU).  The shared ``kernel/ops/ref``
-contract and this family's VMEM tiling limits are documented in
-``docs/KERNELS.md``.
+Dispatch is a fixed rule: every backend runs the XLA implementation
+(:mod:`.xla`).  The Pallas kernel (:mod:`.kernel`) is refused by the TPU
+compiler — Mosaic lowers no scatter and no 1-D gather (the compiler's words
+are in ``docs/KERNELS.md``) — so it runs only through the Pallas interpreter,
+where the tests hold it bit-identical to the XLA path.  ``REPRO_FRONTIER_IMPL``
+overrides the default (CI's ``kernels-interpret`` job sets it to
+``kernel_interpret``).
 """
 
 from __future__ import annotations
 
 import os
 
-import jax
 import jax.numpy as jnp
 
 from . import kernel as _kernel
-from . import ref as _ref
+from . import xla as _xla
+
+
+def resolve(impl: str | None = None) -> str:
+    """The implementation a call with ``impl`` runs."""
+    return impl or os.environ.get("REPRO_FRONTIER_IMPL") or "xla"
 
 
 def frontier_expand(
@@ -26,15 +31,9 @@ def frontier_expand(
     *,
     impl: str | None = None,
 ) -> jnp.ndarray:
-    impl = (
-        impl
-        or os.environ.get("REPRO_FRONTIER_IMPL")
-        or ("kernel" if jax.default_backend() == "tpu" else "reference")
-    )
-    if impl == "kernel":
-        return _kernel.frontier_expand(frontier, src, dst)
+    impl = resolve(impl)
+    if impl == "xla":
+        return _xla.frontier_expand_xla(frontier, src, dst)
     if impl == "kernel_interpret":
         return _kernel.frontier_expand(frontier, src, dst, interpret=True)
-    if impl == "reference":
-        return _ref.frontier_expand_reference(frontier, src, dst)
     raise ValueError(f"unknown impl {impl!r}")

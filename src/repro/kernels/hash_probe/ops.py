@@ -10,7 +10,6 @@ unchanged on a per-shard table.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from . import kernel as _kernel
@@ -18,9 +17,9 @@ from . import ref as _ref
 
 
 def hash_probe(table_keys: jnp.ndarray, query_keys: jnp.ndarray, *, impl: str | None = None):
-    impl = impl or ("kernel" if jax.default_backend() == "tpu" else "reference")
-    if impl == "kernel":
-        return _kernel.hash_probe(table_keys, query_keys)
+    # fixed rule: the TPU compiler refuses the kernel ("Cannot do int
+    # indexing on TPU", docs/KERNELS.md), so it runs only interpreted
+    impl = impl or "reference"
     if impl == "kernel_interpret":
         return _kernel.hash_probe(table_keys, query_keys, interpret=True)
     if impl == "reference":

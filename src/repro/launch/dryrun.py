@@ -126,7 +126,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool, verbose: bool = True,
 
         specs = S.input_specs(cfg, shape, mesh, multi_pod=multi_pod)
 
-        with mesh:
+        with jax.set_mesh(mesh):
             # NOTE donation was tried here (params/opt for train, cache for
             # decode) to mirror the real loop; the CPU backend's buffer
             # assignment got *worse* (+3.7 GiB at 104B train), so the

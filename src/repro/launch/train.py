@@ -29,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 from repro.checkpoint import CheckpointStore
 from repro.configs import ARCH_NAMES, get_config, get_smoke_config
 from repro.data import DataConfig, SyntheticTokenStream
+from repro.launch.mesh import make_mesh as _make_mesh
 from repro.launch.steps import build_train_step
 from repro.models import LM
 from repro.optim import AdamWConfig, adamw_init
@@ -40,7 +41,7 @@ def make_mesh(spec: str):
     assert len(parts) == 2, "--mesh DxM"
     n = parts[0] * parts[1]
     assert n <= len(jax.devices()), f"mesh {spec} needs {n} devices"
-    return jax.make_mesh(parts, ("data", "model"))
+    return _make_mesh(parts, ("data", "model"))
 
 
 class TrainRunner:
@@ -70,7 +71,7 @@ class TrainRunner:
         if self.store is not None and self.store.latest_step() is not None:
             self.restore(self.store.latest_step())
             return "restored"
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             self.params = jax.jit(
                 self.model.init,
                 out_shardings=jax.tree.map(
@@ -117,7 +118,7 @@ class TrainRunner:
         if self._jit is None:
             self._jit = jax.jit(self.step_fn, donate_argnums=(0, 1))
         losses = []
-        with mesh:
+        with jax.set_mesh(mesh):
             t0 = time.time()
             while self.step < steps:
                 host_batch = self.data.next_batch()

@@ -14,11 +14,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-try:  # jax < 0.5 keeps shard_map under jax.experimental
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.kernels.flash_attention import attention as flash_attention
 from repro.kernels.flash_attention.ref import mha_chunked
 
@@ -419,10 +414,6 @@ def moe_apply_shardmap(p, cfg: ArchConfig, x, *, dp_axes=("data",),
     e, k = cfg.moe.n_experts, cfg.moe.top_k
     fsdp = dp_axes if len(dp_axes) > 1 else dp_axes[0]
     mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty:  # `with mesh:` context manager path
-        from jax.interpreters import pxla
-
-        mesh = pxla.thread_resources.env.physical_mesh
     n_dp = 1
     for a in dp_axes:
         n_dp *= mesh.shape[a]
@@ -447,7 +438,7 @@ def moe_apply_shardmap(p, cfg: ArchConfig, x, *, dp_axes=("data",),
         aux = jax.lax.pmean(aux, dp_axes)
         return out.reshape(Bl, Sl, dl), aux
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

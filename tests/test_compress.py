@@ -38,22 +38,14 @@ _COLLECTIVE_SCRIPT = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro.optim.compress import compressed_psum, ef_init
 
-    # version compat: AxisType/jax.shard_map/jax.set_mesh are newer-jax names
-    mesh_kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        mesh_kwargs["axis_types"] = (jax.sharding.AxisType.Explicit,)
-    mesh = jax.make_mesh((4,), ("pod",), **mesh_kwargs)
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-    def set_mesh(m):
-        return jax.set_mesh(m) if hasattr(jax, "set_mesh") else m
+    mesh = jax.make_mesh((4,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Explicit,))
     rng = np.random.default_rng(1)
     # per-pod gradients (4, n): the true mean is the uncompressed target
     g = rng.standard_normal((4, 256)).astype(np.float32)
     target = g.mean(axis=0)
 
-    @partial(shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")),
              out_specs=(P("pod"), P("pod")))
     def step(gi, ei):
         out, new_e = compressed_psum(
@@ -61,7 +53,7 @@ _COLLECTIVE_SCRIPT = textwrap.dedent("""
         )
         return out["w"][None], new_e["w"][None]
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         e = jnp.zeros((4, 256), jnp.float32)
         out, e = step(jnp.asarray(g), e)
     out = np.asarray(out)
@@ -73,7 +65,7 @@ _COLLECTIVE_SCRIPT = textwrap.dedent("""
 
     # error feedback: averaging the SAME grads repeatedly converges to the
     # true mean (residuals re-enter), unlike plain repeated quantization
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         e = jnp.zeros((4, 256), jnp.float32)
         acc = np.zeros(256, np.float32)
         T = 64

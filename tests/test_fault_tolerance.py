@@ -23,6 +23,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _runner(tmp, **kw):
     import jax
 
+    from repro.launch.mesh import make_mesh
     from repro.launch.train import TrainRunner
     from repro.models.config import ArchConfig
 
@@ -30,7 +31,7 @@ def _runner(tmp, **kw):
         name="ft-tiny", family="dense", n_layers=2, d_model=64, n_heads=4,
         n_kv_heads=2, d_ff=128, vocab=256, dtype="float32",
     )
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     return TrainRunner(cfg, mesh, ckpt_dir=tmp, batch=4, seq=16, **kw)
 
 
@@ -100,17 +101,18 @@ _ELASTIC_SCRIPT = textwrap.dedent("""
     import jax, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint import CheckpointStore
+    from repro.launch.mesh import make_mesh
 
     tmp = sys.argv[1]
     store = CheckpointStore(tmp)
     w = np.arange(64, dtype=np.float32).reshape(8, 8)
-    mesh_a = jax.make_mesh((2, 2), ("data", "model"))
+    mesh_a = make_mesh((2, 2), ("data", "model"))
     wa = jax.device_put(w, NamedSharding(mesh_a, P("data", "model")))
     store.save(3, {"w": wa})
 
     for shape, axes in [((4, 1), ("data", "model")), ((1, 4), ("data", "model")),
                         ((8,), ("data",))]:
-        mesh_b = jax.make_mesh(shape, axes)
+        mesh_b = make_mesh(shape, axes)
         sh = {"w": NamedSharding(mesh_b, P("data"))}
         out = store.restore(3, {"w": jax.ShapeDtypeStruct((8, 8), np.float32)},
                             shardings=sh)

@@ -1,8 +1,8 @@
-"""Frontier-expansion kernel parity: Pallas interpret mode vs pure-jnp
-reference, bit-exact, standalone and end-to-end through the traversal engine.
+"""Frontier-expansion kernel parity: Pallas interpret mode vs the XLA
+implementation, bit-exact, standalone and end-to-end through the traversal engine.
 
 The kernel's contract is exact (integer scatter-min — no tolerances): the
-tiled VMEM reduction must match the reference for any frontier/CSR input,
+tiled VMEM reduction must match the XLA path for any frontier/CSR input,
 including the padding paths (lane-aligned widths, ragged edge counts), and
 the whole BFS must produce identical levels/parents through either impl.
 """
@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from repro.core import SequentialGraph, WaitFreeGraph, bfs_parents, build_csr
 from repro.core.workloads import sample_batch
-from repro.kernels.frontier import NBR_INF, frontier_expand, frontier_expand_reference
+from repro.kernels.frontier import NBR_INF, frontier_expand, frontier_expand_xla
 
 KEY_SPACE = 24
 
@@ -34,7 +34,7 @@ def test_frontier_expand_parity_random(S, C, Ce):
     frontier = jnp.asarray(rng.random((S, C)) < 0.3)
     src = jnp.asarray(rng.integers(0, C, Ce).astype(np.int32))
     dst = jnp.asarray(rng.integers(0, C, Ce).astype(np.int32))
-    ref = frontier_expand_reference(frontier, src, dst)
+    ref = frontier_expand_xla(frontier, src, dst)
     ker = frontier_expand(frontier, src, dst, impl="kernel_interpret")
     np.testing.assert_array_equal(np.asarray(ker), np.asarray(ref))
 
@@ -68,7 +68,7 @@ def test_frontier_expand_block_tilings_agree():
     frontier = jnp.asarray(rng.random((S, C)) < 0.25)
     src = jnp.asarray(rng.integers(0, C, Ce).astype(np.int32))
     dst = jnp.asarray(rng.integers(0, C, Ce).astype(np.int32))
-    ref = np.asarray(frontier_expand_reference(frontier, src, dst))
+    ref = np.asarray(frontier_expand_xla(frontier, src, dst))
     for block_s, block_e in [(1, 64), (4, 128), (8, 600), (8, 4096)]:
         got = raw_kernel(
             frontier, src, dst, block_s=block_s, block_e=block_e, interpret=True
@@ -92,11 +92,11 @@ def _churned_graph(seed: int):
 @pytest.mark.parametrize("seed", range(4))
 def test_bfs_through_kernel_matches_reference_and_oracle(seed):
     """End-to-end: the whole level loop through the interpret-mode kernel is
-    bit-identical to the reference impl, and both match the oracle."""
+    bit-identical to the XLA impl, and both match the oracle."""
     g, o, rng = _churned_graph(seed)
     csr = build_csr(g.state)
     keys = jnp.asarray(rng.integers(0, KEY_SPACE, 8).astype(np.int32))
-    lv_ref, par_ref = bfs_parents(csr, keys, impl="reference")
+    lv_ref, par_ref = bfs_parents(csr, keys, impl="xla")
     lv_ker, par_ker = bfs_parents(csr, keys, impl="kernel_interpret")
     np.testing.assert_array_equal(np.asarray(lv_ker), np.asarray(lv_ref))
     np.testing.assert_array_equal(np.asarray(par_ker), np.asarray(par_ref))

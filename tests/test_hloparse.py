@@ -99,8 +99,9 @@ _SHARDED_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.launch import hloparse
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     def f(x, w):
         def body(c, wi):
             return jnp.tanh(c @ wi), None
@@ -109,7 +110,7 @@ _SHARDED_SCRIPT = textwrap.dedent("""
                               sharding=NamedSharding(mesh, P("data", None)))
     ws = jax.ShapeDtypeStruct((8, 256, 256), jnp.float32,
                               sharding=NamedSharding(mesh, P(None, None, "model")))
-    with mesh:
+    with jax.set_mesh(mesh):
         comp = jax.jit(f).lower(xs, ws).compile()
     s = hloparse.summarize(comp.as_text())
     # per-device dot: (64,256)x(256,64) x 8 trips

@@ -6,12 +6,12 @@ import jax.numpy as jnp
 
 from repro.kernels.compact import (
     masked_compact,
-    masked_compact_reference,
+    masked_compact_xla,
     probe_place,
-    probe_place_reference,
+    probe_place_xla,
 )
 from repro.kernels.flash_attention import attention, mha_chunked, mha_reference
-from repro.kernels.frontier import frontier_expand, frontier_expand_reference
+from repro.kernels.frontier import frontier_expand, frontier_expand_xla
 from repro.kernels.hash_probe import hash_probe, hash_probe_reference
 from repro.kernels.paged_attention import paged_attention, paged_attention_reference
 from repro.kernels.ssd_scan import (
@@ -207,14 +207,14 @@ def test_frontier_expand_sweep(S, C, Ce):
     frontier = jnp.asarray(rng.random((S, C)) < 0.2)
     src = jnp.asarray(rng.integers(0, C, Ce).astype(np.int32))
     dst = jnp.asarray(rng.integers(0, C, Ce).astype(np.int32))
-    ref = frontier_expand_reference(frontier, src, dst)
+    ref = frontier_expand_xla(frontier, src, dst)
     got = frontier_expand(frontier, src, dst, impl="kernel_interpret")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
 # ---------------------------------------------------------------------------
 # compaction primitives (state maintenance; deep coverage in
-# test_maintenance.py — these sweep the raw kernels vs the jnp references)
+# test_maintenance.py — these sweep the raw kernels vs the XLA paths)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("R,N,density", [(1, 64, 0.5), (3, 1000, 0.2), (6, 4096, 0.8)])
@@ -222,7 +222,7 @@ def test_masked_compact_sweep(R, N, density):
     rng = np.random.default_rng(R * 17 + N)
     vals = jnp.asarray(rng.integers(-5, 1000, (R, N)).astype(np.int32))
     mask = jnp.asarray(rng.random(N) < density)
-    ref, n_ref = masked_compact_reference(vals, mask, fill=-1)
+    ref, n_ref = masked_compact_xla(vals, mask, fill=-1)
     got, n_got = masked_compact(vals, mask, fill=-1, impl="kernel_interpret")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
     assert int(n_got) == int(n_ref) == int(np.asarray(mask).sum())
@@ -241,7 +241,7 @@ def test_probe_place_sweep(cap, n, max_probes):
     keys = jnp.asarray(rng.choice(100_000, n, replace=False).astype(np.int32))
     home = hash_vertex(keys, cap)
     active = jnp.asarray(rng.random(n) < 0.9)
-    s_ref, o_ref = probe_place_reference(home, active, capacity=cap, max_probes=max_probes)
+    s_ref, o_ref = probe_place_xla(home, active, capacity=cap, max_probes=max_probes)
     s_got, o_got = probe_place(
         home, active, capacity=cap, max_probes=max_probes, impl="kernel_interpret"
     )
@@ -266,10 +266,10 @@ def test_probe_place_sweep(cap, n, max_probes):
 
 
 def test_probe_slot_replica_pins_hashing():
-    """compact.ref keeps a local probe_slot replica (kernel families are
+    """compact.xla keeps a local probe_slot replica (kernel families are
     import-free of repro.core); it must stay bit-identical to the real one."""
     from repro.core.hashing import probe_slot
-    from repro.kernels.compact.ref import _probe_slot
+    from repro.kernels.compact.xla import _probe_slot
 
     home = jnp.asarray(np.arange(0, 512, 7, dtype=np.int32) % 256)
     for step in (0, 1, 5, 31):
@@ -287,6 +287,6 @@ def test_probe_place_overflow_is_flagged():
     keys = jnp.asarray(np.arange(40, dtype=np.int32))
     home = hash_vertex(keys, 32)
     active = jnp.ones(40, bool)
-    _, o_ref = probe_place_reference(home, active, capacity=32, max_probes=2)
+    _, o_ref = probe_place_xla(home, active, capacity=32, max_probes=2)
     _, o_got = probe_place(home, active, capacity=32, max_probes=2, impl="kernel_interpret")
     assert bool(o_ref) and bool(o_got)

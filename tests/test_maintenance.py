@@ -223,7 +223,7 @@ def test_growth_seeds_delta_queue_with_snapshot_compact():
 
 
 # ---------------------------------------------------------------------------
-# delta-merge: the device searchsorted splice
+# delta-merge: the device splice
 # ---------------------------------------------------------------------------
 
 
@@ -290,3 +290,41 @@ def test_delta_merge_via_graph_flag():
             _apply_both(g, o, ops, us, vs)
         _assert_same_fields(g.traversal_csr(), build_csr(g.state), impl)
         assert g.snapshot() == (o.vertices, o.edges)
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_delta_merge_past_int32_composite_keys(seed):
+    """v_cap * e_cap = 2**31: the size at which a composite ``src * Ce +
+    lane`` int32 merge key overflows (and the old guard handed the fold to
+    the host).  The device fold sorts by (source slot, lane) as two keys and
+    stays bit-identical to a rebuild on churned graphs of that size."""
+    cv, ce = 2**12, 2**19
+    assert cv * ce >= 2**31
+    rng = np.random.default_rng(seed)
+    g = WaitFreeGraph(cv, ce, mode="fpsp", maintenance_impl="device")
+    o = SequentialGraph()
+    keys = rng.choice(2**30, 1500, replace=False).astype(np.int32)
+    n_e = 6000
+    ops = np.concatenate([np.full(keys.size, OP_ADD_VERTEX), np.full(n_e, OP_ADD_EDGE)])
+    us = np.concatenate([keys, rng.choice(keys, n_e)])
+    vs = np.concatenate([np.zeros(keys.size, np.int32), rng.choice(keys, n_e)])
+    _apply_both(g, o, ops.astype(np.int32), us.astype(np.int32), vs.astype(np.int32))
+    csr = g.traversal_csr()
+    assert (g.state.v_capacity, g.state.e_capacity) == (cv, ce)
+    for _ in range(3):
+        n = 512
+        kill = rng.choice(keys, 16, replace=False)
+        ops = np.concatenate([
+            np.full(16, OP_REMOVE_VERTEX),
+            rng.choice([OP_ADD_EDGE, 5, OP_ADD_EDGE], n),  # 5 = OP_REMOVE_EDGE
+            np.full(8, OP_ADD_VERTEX),
+        ]).astype(np.int32)
+        us = np.concatenate([kill, rng.choice(keys, n), kill[:8]]).astype(np.int32)
+        vs = np.concatenate([np.zeros(16), rng.choice(keys, n), np.zeros(8)]).astype(np.int32)
+        _apply_both(g, o, ops, us, vs)
+        folded = traversal.apply_delta(csr, g.state, ops, us, vs, impl="device")
+        want = build_csr(g.state)
+        _assert_same_fields(folded, want, "device")
+        _assert_same_fields(traversal.apply_delta(csr, g.state, ops, us, vs, impl="host"), want, "host")
+        csr = folded
+    assert g.snapshot() == (o.vertices, o.edges)

@@ -8,6 +8,7 @@ import pytest
 
 from repro.configs import get_smoke_config
 from repro.data import DataConfig, SyntheticTokenStream
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import build_prefill_step, build_train_step
 from repro.launch.train import TrainRunner
 from repro.models import LM
@@ -21,7 +22,7 @@ TINY = ArchConfig(
 
 
 def test_train_loss_decreases():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     runner = TrainRunner(TINY, mesh, ckpt_dir=None, batch=8, seq=32)
     runner.init_or_restore()
     losses = runner.train(30, log_every=5, save_every=0, log=lambda *a: None)
@@ -32,7 +33,7 @@ def test_train_loss_decreases():
 
 def test_train_then_serve():
     """The whole lifecycle: train params, hand them to the serving engine."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     runner = TrainRunner(TINY, mesh, ckpt_dir=None, batch=4, seq=32)
     runner.init_or_restore()
     runner.train(3, log_every=10, save_every=0, log=lambda *a: None)
@@ -55,7 +56,7 @@ def test_prefill_matches_train_forward_logits():
     toks = jnp.asarray(
         np.random.default_rng(0).integers(0, cfg.vocab, (2, 16)), jnp.int32
     )
-    with jax.make_mesh((1, 1), ("data", "model")):
+    with jax.set_mesh(make_mesh((1, 1), ("data", "model"))):
         out = prefill(params, {"tokens": toks})
         hid, _, _ = model.hidden_states(params, toks, run=run)
         ref = model._logits(params, hid[:, -1:])
@@ -78,7 +79,7 @@ def test_run_knobs_numerically_equivalent(knobs):
     )
     base_run = {"sp": True, "remat": False, "dp_axes": ("data",),
                 "attn_impl": "chunked", "loss_chunk": 512}
-    with jax.make_mesh((1, 1), ("data", "model")):
+    with jax.set_mesh(make_mesh((1, 1), ("data", "model"))):
         ref, _, _ = model.hidden_states(params, toks, run=base_run)
         got, _, _ = model.hidden_states(params, toks, run={**base_run, **knobs})
     np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
@@ -111,7 +112,7 @@ def test_accum_equals_no_accum():
         "mask": jnp.ones((8, 16), jnp.float32),
     }
     outs = []
-    with jax.make_mesh((1, 1), ("data", "model")):
+    with jax.set_mesh(make_mesh((1, 1), ("data", "model"))):
         for accum in (1, 4):
             step, _, _ = build_train_step(cfg, multi_pod=False, accum=accum)
             opt = adamw_init(params)

@@ -418,3 +418,26 @@ def test_randomized_get_path_matches_oracle(mode, seed):
         assert len(set(got)) == len(got)  # simple path
         for a, b in zip(got, got[1:]):
             assert (a, b) in oracle.edges, (got, (a, b))
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_repeated_sources_answer_per_pair(seed):
+    """A query batch that repeats its sources answers every pair on its own
+    row: reachable and get_path agree with the oracle pair by pair, and the
+    batched k-hop equals one k-hop call per source."""
+    g, oracle, rng = _build_random(seed, "fpsp")
+    us = rng.choice(4, 48).astype(np.int32)  # few distinct sources
+    vs = rng.integers(0, KEY_SPACE, 48).astype(np.int32)
+    assert g.reachable(us, vs).tolist() == [
+        oracle.reachable(int(a), int(b)) for a, b in zip(us, vs)
+    ]
+    paths = g.get_path_batch(us, vs)
+    for u, v, p in zip(us.tolist(), vs.tolist(), paths):
+        want = oracle.path(u, v)
+        assert (p is None) == (want is None)
+        if p is not None:
+            assert p[0] == u and p[-1] == v and len(p) == len(want)
+    srcs = [int(s) for s in us[:6]]
+    assert g.khop_batch(srcs, 2) == [g.khop(s, 2) for s in srcs] == [
+        oracle.khop(s, 2) for s in srcs
+    ]
