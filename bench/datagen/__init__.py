@@ -1,0 +1,1 @@
+"""Data generators, one module per kind, named by a configuration's ``generator``."""
