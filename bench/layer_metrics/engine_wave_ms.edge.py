@@ -1,0 +1,9 @@
+"""Engines: device time of the ops under the ``engine.edge_wave`` scope
+inside each ``bench.apply`` span, per batch (ms)."""
+
+from bench import spans
+
+
+def read(ctx):
+    busy = spans.scoped_busy(ctx.trace, spans.op_scopes(ctx.trace), "engine.edge_wave", "bench.apply")
+    return sum(busy) / len(busy) * 1e-6 if busy else None

@@ -34,6 +34,10 @@ against ``repro.core.oracle``).  Structure:
 Everything is int32/bool — results are asserted *exactly* equal to the
 oracle, not allclose.
 
+Each wave's device ops carry its name (``jax.named_scope``):
+``engine.vertex_wave``, ``engine.stab_wave`` and ``engine.edge_wave``, so a
+profiler trace splits the pass's device time by wave.
+
 Each op linearizes at its phase stamp: a batch's results are exactly those
 of the phase-ordered sequential execution.  Where this engine sits in the
 paper-to-code map — and how sharding runs it unchanged per shard — is
@@ -77,6 +81,7 @@ def _sort_by(keys, *arrays):
 # A. vertex wave
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("engine.vertex_wave")
 def _vertex_wave(state: GraphState, batch: OpBatch):
     op, u, phase = batch.op, batch.u, batch.phase
     n = op.shape[0]
@@ -209,6 +214,7 @@ def _stab_scan(state: GraphState, tkeys, tphases, t_set, ev_live, ev_inc, qkeys,
     return out_live[nt:], out_inc[nt:], loc.overflow
 
 
+@jax.named_scope("engine.stab_wave")
 def _stabbing_wave(state: GraphState, batch: OpBatch, is_eop, ev_live, ev_inc, is_vop):
     op, u, v, phase = batch.op, batch.u, batch.v, batch.phase
     n = op.shape[0]
@@ -238,6 +244,7 @@ def _stabbing_wave(state: GraphState, batch: OpBatch, is_eop, ev_live, ev_inc, i
 # C. edge wave
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("engine.edge_wave")
 def _edge_wave(state: GraphState, batch: OpBatch, is_eop, endpoint):
     op, u, v, phase = batch.op, batch.u, batch.v, batch.phase
     n = op.shape[0]

@@ -26,7 +26,10 @@ sub-batch holds only its owned ops, and endpoint liveness arrives from the
 cross-shard stabbing wave instead of the local table; the partitioned FPSP
 entry point is :func:`settle_edges_fpsp`, whose conflict mask reduces to
 duplicate ``(u, v)`` detection because the stab answers already fold in
-every concurrent vertex op.  Paper-to-code map: ``docs/ARCHITECTURE.md``.
+every concurrent vertex op.  The fast path's vertex half, its endpoint
+reads and its edge half carry the engine's wave names
+(``engine.vertex_wave``, ``engine.stab_wave``, ``engine.edge_wave``) on the
+device.  Paper-to-code map: ``docs/ARCHITECTURE.md``.
 """
 
 from __future__ import annotations
@@ -123,17 +126,12 @@ def _conflict_mask(batch: OpBatch):
     return (v_conf | e_conf) & (is_vop | is_eop), is_vop, is_eop, v_conf, e_conf, edge_dup
 
 
-def _fast_apply(state: GraphState, batch: OpBatch, fast: jnp.ndarray):
-    """Resolve conflict-free ops straight from the table state."""
-    op, u, v = batch.op, batch.u, batch.v
-    n = op.shape[0]
-
-    is_vop = (op == OP_ADD_VERTEX) | (op == OP_REMOVE_VERTEX) | (op == OP_CONTAINS_VERTEX)
-    is_eop = ~is_vop & (op != OP_NOP)
-    fv = fast & is_vop
-    fe = fast & is_eop
-
-    # ---- vertices ----
+@jax.named_scope("engine.vertex_wave")
+def _fast_vertices(state: GraphState, batch: OpBatch, fv: jnp.ndarray):
+    """The vertex half of the fast path: conflict-free vertex ops resolved
+    straight from the table.  Returns ``(state', success, overflow,
+    n_inserted, claim_rounds)``."""
+    op, u = batch.op, batch.u
     vloc = locate_vertices(state.v_key, jnp.where(fv, u, _INT32_MAX), fv)
     vsafe = jnp.where(vloc.found, vloc.slot, 0)
     vlive = jnp.where(vloc.found, state.v_live[vsafe], False)
@@ -163,72 +161,50 @@ def _fast_apply(state: GraphState, batch: OpBatch, fast: jnp.ndarray):
     v_inc_new = v_inc_new.at[islot].set(0, mode="drop")
 
     state = state._replace(v_key=v_key_new, v_live=v_live_new, v_inc=v_inc_new)
+    n_ins = jnp.sum(need_ins & (new_slots >= 0)).astype(jnp.int32)
+    return state, v_success, vloc.overflow | v_over, n_ins, v_rounds
 
-    # ---- edges ----
-    # endpoints: table state is authoritative (no concurrent vertex ops on
-    # them — that is the fast-path precondition)
+
+@jax.named_scope("engine.stab_wave")
+def _table_endpoints(state: GraphState, batch: OpBatch, fe: jnp.ndarray):
+    """Endpoint (live, inc) of the fast edge lanes, read from the table:
+    the table is authoritative for them (no concurrent vertex ops on their
+    endpoints — that is the fast-path precondition).  Returns ``((u_live,
+    u_inc, v_live, v_inc), overflow)``."""
+    u, v = batch.u, batch.v
     uloc = locate_vertices(state.v_key, jnp.where(fe, u, _INT32_MAX), fe)
-    vloc2 = locate_vertices(state.v_key, jnp.where(fe, v, _INT32_MAX), fe)
+    vloc = locate_vertices(state.v_key, jnp.where(fe, v, _INT32_MAX), fe)
     usafe = jnp.where(uloc.found, uloc.slot, 0)
-    vsafe2 = jnp.where(vloc2.found, vloc2.slot, 0)
+    vsafe = jnp.where(vloc.found, vloc.slot, 0)
     u_live = jnp.where(uloc.found, state.v_live[usafe], False)
-    v_live = jnp.where(vloc2.found, state.v_live[vsafe2], False)
+    v_live = jnp.where(vloc.found, state.v_live[vsafe], False)
     u_inc = jnp.where(uloc.found, state.v_inc[usafe], ABSENT_INC)
-    v_inc = jnp.where(vloc2.found, state.v_inc[vsafe2], ABSENT_INC)
-    eligible = u_live & v_live & fe
+    v_inc = jnp.where(vloc.found, state.v_inc[vsafe], ABSENT_INC)
+    return (u_live, u_inc, v_live, v_inc), uloc.overflow | vloc.overflow
 
-    eloc = locate_edges(
-        state.e_key_u, state.e_key_v,
-        jnp.where(fe, u, _INT32_MAX), jnp.where(fe, v, _INT32_MAX), fe,
-    )
-    esafe = jnp.where(eloc.found, eloc.slot, 0)
-    e_valid = (
-        eloc.found
-        & state.e_live[esafe]
-        & (state.e_inc_u[esafe] == u_inc)
-        & (state.e_inc_v[esafe] == v_inc)
-        & eligible
-    )
 
-    adde = fe & (op == OP_ADD_EDGE)
-    reme = fe & (op == OP_REMOVE_EDGE)
-    cone = fe & (op == OP_CONTAINS_EDGE)
-    e_success = (adde & eligible & ~e_valid) | ((reme | cone) & e_valid)
+def _fast_apply(state: GraphState, batch: OpBatch, fast: jnp.ndarray):
+    """Resolve conflict-free ops straight from the table: the vertex ops,
+    then the edge ops against the post-vertex table."""
+    op = batch.op
+    is_vop = (op == OP_ADD_VERTEX) | (op == OP_REMOVE_VERTEX) | (op == OP_CONTAINS_VERTEX)
+    is_eop = ~is_vop & (op != OP_NOP)
+    fv = fast & is_vop
+    fe = fast & is_eop
 
-    ecap = state.e_key_u.shape[0]
-    ewr = ((adde | reme) & e_success & eloc.found)
-    ewslot = jnp.where(ewr, eloc.slot, ecap)
-    e_live_new = state.e_live.at[ewslot].set(adde & e_success, mode="drop")
-    e_bu_new = state.e_inc_u.at[ewslot].set(u_inc, mode="drop")
-    e_bv_new = state.e_inc_v.at[ewslot].set(v_inc, mode="drop")
-
-    e_need_ins = adde & e_success & ~eloc.found
-    e_ku_new, e_kv_new, e_new_slots, e_over, e_rounds = claim_edge_slots(
-        state.e_key_u, state.e_key_v,
-        jnp.where(e_need_ins, u, _INT32_MAX), jnp.where(e_need_ins, v, _INT32_MAX),
-        e_need_ins,
-    )
-    eislot = jnp.where(e_need_ins & (e_new_slots >= 0), e_new_slots, ecap)
-    e_live_new = e_live_new.at[eislot].set(True, mode="drop")
-    e_bu_new = e_bu_new.at[eislot].set(u_inc, mode="drop")
-    e_bv_new = e_bv_new.at[eislot].set(v_inc, mode="drop")
-
-    state = state._replace(
-        e_key_u=e_ku_new, e_key_v=e_kv_new,
-        e_live=e_live_new, e_inc_u=e_bu_new, e_inc_v=e_bv_new,
-    )
+    state, v_success, v_over, v_ins, v_rounds = _fast_vertices(state, batch, fv)
+    endpoint, s_over = _table_endpoints(state, batch, fe)
+    state, e_success, e_over, e_ins, e_rounds = _fast_apply_edges(state, batch, fe, endpoint)
 
     success = jnp.where(fv, v_success, jnp.where(fe, e_success, False))
-    overflow = vloc.overflow | uloc.overflow | vloc2.overflow | eloc.overflow | v_over | e_over
-    n_ins = (
-        jnp.sum(need_ins & (new_slots >= 0)) + jnp.sum(e_need_ins & (e_new_slots >= 0))
-    ).astype(jnp.int32)
-    return state, success, overflow, n_ins, v_rounds + e_rounds
+    return state, success, v_over | s_over | e_over, v_ins + e_ins, v_rounds + e_rounds
 
 
+@jax.named_scope("engine.edge_wave")
 def _fast_apply_edges(state: GraphState, batch: OpBatch, fe, endpoint):
-    """The edge half of :func:`_fast_apply`, fed externally settled endpoint
-    (live, inc)-at-phase answers instead of table reads.
+    """The edge half of the fast path, fed endpoint (live, inc)-at-phase
+    answers: the table's (:func:`_table_endpoints`) in :func:`_fast_apply`,
+    the stabbing wave's in :func:`settle_edges_fpsp`.
 
     Under vertex partitioning (:mod:`repro.core.sharding`) a shard cannot
     read non-owned endpoints from its local table — the stabbing wave's

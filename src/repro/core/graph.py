@@ -73,6 +73,14 @@ def _bucket_size(n: int) -> int:
     return max(64, 1 << max(n - 1, 1).bit_length())
 
 
+def _as_int32(ops, us, vs) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """A batch's op codes and endpoints as int32 host arrays (``vs`` zeros
+    when the batch has no second endpoint)."""
+    us0 = np.asarray(us, np.int32)
+    vs0 = np.zeros_like(us0) if vs is None else np.asarray(vs, np.int32)
+    return np.asarray(ops, np.int32), us0, vs0
+
+
 @jax.jit
 def _live_counts(state: GraphState):
     v = jnp.sum(state.v_live)
@@ -157,8 +165,9 @@ class WaitFreeGraph:
 
     ``obs`` enables wait-free telemetry (:mod:`repro.obs`): ``None`` defers
     to the ``REPRO_OBS`` env var, ``True`` attaches a fresh
-    :class:`repro.obs.Registry`, ``False`` forces the zero-cost no-op, and
-    a registry instance is shared as-is.  Every metric is derived from
+    :class:`repro.obs.Registry`, ``False`` forces the no-op registry
+    (whose spans are profiler annotations only), and a registry instance
+    is shared as-is.  Every metric is derived from
     arrays the jitted programs compute regardless, so the flag never
     changes graph state or query answers (bit-identity pinned by
     ``tests/test_obs.py``); catalog in ``docs/OBSERVABILITY.md``.
@@ -266,12 +275,6 @@ class WaitFreeGraph:
         if n == 0:
             # nothing to resolve: skip the padded engine dispatch entirely
             return np.zeros(0, bool)
-        # read-only batches (contains/NOP only) leave the abstract graph
-        # unchanged, so the cached traversal snapshot stays valid — keep it
-        # across the state swap below instead of forcing a CSR rebuild.
-        ops0 = np.asarray(ops, np.int32)
-        us0 = np.asarray(us, np.int32)
-        vs0 = np.zeros_like(us0) if vs is None else np.asarray(vs, np.int32)
         reg = self.obs
         with obsm.use(reg):
             reg.counter("apply.batches")
@@ -279,85 +282,105 @@ class WaitFreeGraph:
             reg.hist("apply.batch_size", n)
             if self.n_shards > 1:
                 with reg.span("graph.apply_sharded"):
-                    return self._apply_sharded(ops0, us0, vs0)
+                    return self._apply_sharded(*_as_int32(ops, us, vs))
             with reg.span("graph.apply"):
-                return self._apply_dense(ops0, us0, vs0)
+                return self._apply_dense(ops, us, vs)
 
-    def _apply_dense(self, ops0, us0, vs0) -> np.ndarray:
+    def _apply_dense(self, ops, us, vs) -> np.ndarray:
         """The ``n_shards == 1`` engine dispatch behind :meth:`apply` (runs
-        inside the obs ``use`` scope the wrapper installed)."""
-        n = ops0.shape[0]
-        mutating = bool(np.isin(ops0, _MUTATING_OPS).any())
-        saved_csr = None if mutating else self._csr
-        # the pending-delta queue (base snapshot + unpadded batches since the
-        # last query) survives the state swap below: read-only batches carry
-        # it unchanged, mutating batches append to it so the next query folds
-        # the whole queue in one apply_delta (lazy: an update-heavy stream
-        # between queries pays nothing per batch, one fold per query epoch)
-        delta_base, delta_batches = self._delta_base, self._delta_batches
-        if mutating and self.csr_maintenance == "delta" and self._csr is not None:
-            delta_base, delta_batches = self._csr, []
-        bucket = _bucket_size(n)
-        ops, us, vs = ops0, us0, vs0
-        if bucket != n:
-            pad = np.zeros(bucket - n, np.int32)  # OP_NOP = 0
-            ops = np.concatenate([ops0, pad])
-            us = np.concatenate([us0, pad])
-            vs = np.concatenate([vs0, pad])
-        batch = make_batch(ops, us, vs, phase_base=self._phase)
-        self._phase += batch.size
-        apply_fn = engine.apply_batch if self.mode == "waitfree" else fastpath.apply_batch_fpsp
-
+        inside the obs ``use`` scope and the ``graph.apply`` span the
+        wrapper opened).  Its child spans follow one another and cover the
+        call: ``prepare``, then per attempt ``dispatch``, ``wait`` and
+        ``growth_check``, then ``readback``, or ``grow`` and the next
+        attempt."""
+        reg = self.obs
+        with reg.span("graph.apply.prepare"):
+            ops0, us0, vs0 = _as_int32(ops, us, vs)
+            n = ops0.shape[0]
+            # read-only batches (contains/NOP only) leave the abstract graph
+            # unchanged, so the cached traversal snapshot stays valid — keep
+            # it across the state swap instead of forcing a CSR rebuild.
+            mutating = bool(np.isin(ops0, _MUTATING_OPS).any())
+            bucket = _bucket_size(n)
+            ops, us, vs = ops0, us0, vs0
+            if bucket != n:
+                pad = np.zeros(bucket - n, np.int32)  # OP_NOP = 0
+                ops = np.concatenate([ops0, pad])
+                us = np.concatenate([us0, pad])
+                vs = np.concatenate([vs0, pad])
+            batch = make_batch(ops, us, vs, phase_base=self._phase)
+            self._phase += batch.size
+            apply_fn = (
+                engine.apply_batch if self.mode == "waitfree" else fastpath.apply_batch_fpsp
+            )
         self._grow_csr = None
         for attempt in range(_MAX_GROW_ATTEMPTS):
             # keep the pre-state alive for transactional retry
             pre = self.state
-            res = apply_fn(pre, batch)
-            if bool(res.ok) and not self._needs_growth(res.state):
-                # the successful attempt alone feeds the obs counters —
-                # discarded growth attempts re-run the same lanes and would
-                # double-count them
-                if self.obs.enabled:
-                    self._record_engine_stats(self.obs, res.stats)
-                grow_csr = self._grow_csr
-                self.state = res.state
-                if attempt > 0:
-                    # growth rehashed the tables: every slot moved, so both
-                    # the saved snapshot's and the queue's bases are void —
-                    # the state setter already dropped them.  The rehash
-                    # pre-compacted the grown state's snapshot, though
-                    # (maintenance "snapshot-compact"): queue this batch
-                    # against it so the next query pays one delta fold, not
-                    # a full rebuild.
-                    if (
-                        mutating
-                        and grow_csr is not None
-                        and self.csr_maintenance == "delta"
-                    ):
-                        self._delta_base = grow_csr
-                        self._delta_batches = [(ops0, us0, vs0)]
-                    return np.asarray(res.success)[:n]
-                if not mutating:
-                    # abstractly identical pre/post state: the saved snapshot
-                    # (own references to the old tables) and any pending
-                    # queue stay exactly as valid as before the batch
-                    self._csr = saved_csr
-                    self._delta_base = delta_base
-                    self._delta_batches = delta_batches
-                elif delta_base is not None and self.csr_maintenance == "delta":
-                    # queue the batch against the remembered base snapshot;
-                    # traversal_csr() folds the queue on the next query.  A
-                    # queue past the fold's own fallback threshold would
-                    # rebuild anyway — drop it and stop accumulating.
-                    delta_batches = delta_batches + [(ops0, us0, vs0)]
-                    if sum(b[0].size for b in delta_batches) > delta_base.e_capacity // 4:
-                        delta_base, delta_batches = None, []
-                    self._delta_base = delta_base
-                    self._delta_batches = delta_batches
-                return np.asarray(res.success)[:n]
+            with reg.span("graph.apply.dispatch"):
+                res = apply_fn(pre, batch)
+            with reg.span("graph.apply.wait"):
+                ok = bool(res.ok)
+            if ok:
+                with reg.span("graph.apply.growth_check"):
+                    ok = not self._needs_growth(res.state)
+            if ok:
+                with reg.span("graph.apply.readback"):
+                    success = np.asarray(res.success)[:n]
+                    self._install(res, attempt, mutating, (ops0, us0, vs0))
+                return success
             # discard post-state; grow from pre-state; retry the same batch
-            self.state = self._grow(pre)
+            with reg.span("graph.apply.grow"):
+                self.state = self._grow(pre)
         raise RuntimeError("graph growth did not converge")
+
+    def _install(self, res, attempt: int, mutating: bool, batch0) -> None:
+        """Install a successful attempt's post-state and carry the cached
+        snapshot and the pending-delta queue across the swap.
+
+        The queue (base snapshot + unpadded batches since the last query)
+        survives the swap: read-only batches carry it unchanged, mutating
+        batches append to it so the next query folds the whole queue in one
+        apply_delta (lazy: an update-heavy stream between queries pays
+        nothing per batch, one fold per query epoch)."""
+        saved_csr = None if mutating else self._csr
+        delta_base, delta_batches = self._delta_base, self._delta_batches
+        if mutating and self.csr_maintenance == "delta" and self._csr is not None:
+            delta_base, delta_batches = self._csr, []
+        # the successful attempt alone feeds the obs counters — discarded
+        # growth attempts re-run the same lanes and would double-count them
+        if self.obs.enabled:
+            self._record_engine_stats(self.obs, res.stats)
+        grow_csr = self._grow_csr
+        self.state = res.state
+        if attempt > 0:
+            # growth rehashed the tables: every slot moved, so both the
+            # saved snapshot's and the queue's bases are void — the state
+            # setter already dropped them.  The rehash pre-compacted the
+            # grown state's snapshot, though (maintenance
+            # "snapshot-compact"): queue this batch against it so the next
+            # query pays one delta fold, not a full rebuild.
+            if mutating and grow_csr is not None and self.csr_maintenance == "delta":
+                self._delta_base = grow_csr
+                self._delta_batches = [batch0]
+            return
+        if not mutating:
+            # abstractly identical pre/post state: the saved snapshot (own
+            # references to the old tables) and any pending queue stay
+            # exactly as valid as before the batch
+            self._csr = saved_csr
+            self._delta_base = delta_base
+            self._delta_batches = delta_batches
+        elif delta_base is not None and self.csr_maintenance == "delta":
+            # queue the batch against the remembered base snapshot;
+            # traversal_csr() folds the queue on the next query.  A queue
+            # past the fold's own fallback threshold would rebuild anyway —
+            # drop it and stop accumulating.
+            delta_batches = delta_batches + [batch0]
+            if sum(b[0].size for b in delta_batches) > delta_base.e_capacity // 4:
+                delta_base, delta_batches = None, []
+            self._delta_base = delta_base
+            self._delta_batches = delta_batches
 
     def _record_engine_stats(self, reg, stats) -> None:
         """Fold one successful engine pass's stats vector (types.STAT_*)
@@ -777,22 +800,34 @@ class WaitFreeGraph:
         return self.bfs_batch([u])[0]
 
     def bfs_batch(self, sources: Sequence[int]) -> List[Dict[int, int]]:
-        """Batched BFS: one level map per source, all against one snapshot."""
-        pk, n = self._pad_keys(sources)
-        csr = self.traversal_csr()
-        levels = np.asarray(traversal.bfs_levels(csr, pk, impl=self.traversal_impl))[:n]
-        if self.obs.enabled:
-            # frontier iterations per source = deepest reached level (the
-            # level map is computed regardless — obs only reduces it)
-            self.obs.counter("query.bfs", n)
-            self.obs.hist(
-                "bfs.depth", [int(max(row.max(initial=0), 0)) for row in levels]
-            )
-        v_key = np.asarray(csr.v_key)
-        out = []
-        for row in levels:
-            hit = np.nonzero(row >= 0)[0]
-            out.append({int(v_key[j]): int(row[j]) for j in hit})
+        """Batched BFS: one level map per source, all against one snapshot.
+
+        Its child spans follow one another and cover the call: the
+        snapshot, the dispatch of the level loop, the wait for the level
+        maps, and the maps turned into dicts."""
+        reg = self.obs
+        with obsm.use(reg), reg.span("graph.bfs_batch"):
+            with reg.span("graph.bfs_batch.snapshot"):
+                pk, n = self._pad_keys(sources)
+                csr = self.traversal_csr()
+            with reg.span("graph.bfs_batch.dispatch"):
+                levels = traversal.bfs_levels(csr, pk, impl=self.traversal_impl)
+            with reg.span("graph.bfs_batch.readback"):
+                levels = np.asarray(levels)[:n]
+                if reg.enabled:
+                    # frontier iterations per source = deepest reached level
+                    # (the level map is computed regardless — obs only
+                    # reduces it)
+                    reg.counter("query.bfs", n)
+                    reg.hist(
+                        "bfs.depth", [int(max(row.max(initial=0), 0)) for row in levels]
+                    )
+            with reg.span("graph.bfs_batch.to_dicts"):
+                v_key = np.asarray(csr.v_key)
+                out = []
+                for row in levels:
+                    hit = np.nonzero(row >= 0)[0]
+                    out.append({int(v_key[j]): int(row[j]) for j in hit})
         return out
 
     def khop(self, u: int, k: int) -> Set[int]:

@@ -499,8 +499,11 @@ def _bfs_from_slots(
 
     One :func:`frontier_expand` per level: the scatter-min result is both
     the discovery mask (min < NBR_INF) and the parent pointer of every
-    newly reached slot.  An ``n_edges == 0`` snapshot returns the source-
-    only maps without entering the loop at all.
+    newly reached slot.  On the device a level's ops carry the names
+    ``traversal.frontier_expand`` (the expansion) and
+    ``traversal.level_update`` (the rest of the level).  An
+    ``n_edges == 0`` snapshot returns the source-only maps without
+    entering the loop at all.
     """
     cv = csr.v_capacity
     n_src = slot.shape[0]
@@ -519,12 +522,14 @@ def _bfs_from_slots(
 
     def body(carry):
         levels, parents, frontier, depth = carry
-        nbr = frontier_expand(frontier, csr.src, csr.dst, impl=impl)
-        new = (nbr != NBR_INF) & (levels == _NO_LEVEL)
-        new = new.at[:, cv].set(False)
-        levels = jnp.where(new, depth + 1, levels)
-        parents = jnp.where(new, nbr, parents)
-        return levels, parents, new, depth + 1
+        with jax.named_scope("traversal.frontier_expand"):
+            nbr = frontier_expand(frontier, csr.src, csr.dst, impl=impl)
+        with jax.named_scope("traversal.level_update"):
+            new = (nbr != NBR_INF) & (levels == _NO_LEVEL)
+            new = new.at[:, cv].set(False)
+            levels = jnp.where(new, depth + 1, levels)
+            parents = jnp.where(new, nbr, parents)
+            return levels, parents, new, depth + 1
 
     init = (levels, parents, frontier, jnp.int32(0))
     levels, parents, _, _ = jax.lax.cond(

@@ -6,10 +6,11 @@ The paper's performance argument is statistical — the FPSP slow path is
 instead of inferring them from wall clock.  Two halves:
 
 * :mod:`repro.obs.metrics` — a thread-safe registry of counters, gauges,
-  integer histograms, float samples, context-manager spans (wall-clock
-  timing) and bounded structured events, plus the no-op twin every code
-  path holds when observability is off.  Enable via
-  ``WaitFreeGraph(obs=...)`` or the ``REPRO_OBS`` environment variable.
+  integer histograms, context-manager spans (``jax.profiler`` annotations,
+  timed on the wall clock when enabled) and bounded structured events,
+  plus the no-op twin every code path holds when observability is off.
+  Enable via ``WaitFreeGraph(obs=...)`` or the ``REPRO_OBS`` environment
+  variable.
 * :mod:`repro.obs.probes` — post-hoc probe-chain health derivations over
   the hash tables (physical per-table histograms, the shard-count-invariant
   canonical-directory histogram).
@@ -21,14 +22,17 @@ post-device host reductions.  Enabling observability never changes a jitted
 program, so obs-on and obs-off runs produce byte-identical graph states and
 query answers (pinned by ``tests/test_obs.py``).  When disabled, every
 recording call is a method on the shared no-op registry: no locks, no
-dict writes, no device syncs.
+dict writes, no device syncs; a span costs one profiler annotation, which
+is dropped unless a profiler session is active.
 
 Metric catalog, span naming convention, and the ``dump()`` JSON schema:
 ``docs/OBSERVABILITY.md``.
 """
 
 from .metrics import (
+    DEVICE_SCOPES,
     NOOP,
+    SPAN_PREFIXES,
     NoopRegistry,
     Registry,
     active,
@@ -38,7 +42,6 @@ from .metrics import (
     from_env,
     gauge,
     hist,
-    observe,
     resolve,
     span,
     use,
@@ -55,8 +58,9 @@ __all__ = [
     "counter",
     "gauge",
     "hist",
-    "observe",
     "event",
     "span",
     "fastpath_frac",
+    "SPAN_PREFIXES",
+    "DEVICE_SCOPES",
 ]

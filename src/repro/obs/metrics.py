@@ -7,9 +7,12 @@ Design constraints (see ``docs/OBSERVABILITY.md`` for the full contract):
   module touches a device array; callers reduce on the host and pass plain
   ints/floats.  That is what makes the obs-on/obs-off bit-identity pin of
   ``tests/test_obs.py`` possible.
-* **Zero-cost off switch** — disabled code paths hold :data:`NOOP`, whose
-  methods are empty and whose ``span`` returns one shared null context
-  manager.  No locks, no allocation, no branching beyond the call itself.
+* **Spans on the profiler's clock** — every ``span`` opens a
+  ``jax.profiler.TraceAnnotation``, on and off alike, so a profiler session
+  sees the program's sections on the same clock as the device's ops.  An
+  enabled registry also keeps each span's wall time.  Off, a span costs one
+  annotation object, which the profiler drops unless a session is active;
+  the other recorders are empty methods.
 * **Exact integer histograms** — claim rounds, probe lengths, queue depths
   and frontier depths are small ints; the histogram stores exact per-value
   counts (not bucketed approximations), so determinism tests can compare
@@ -29,15 +32,31 @@ import threading
 import time
 from typing import Dict, Iterable, List, Optional, Union
 
+from jax.profiler import TraceAnnotation
+
+# the first part of every span name the program opens (catalog:
+# docs/OBSERVABILITY.md); a trace reader keeps the host spans that start
+# with one of these
+SPAN_PREFIXES = ("graph.", "phase.", "csr.", "maintenance.", "serving.")
+
+# the ``jax.named_scope`` names the jitted programs give their device ops
+DEVICE_SCOPES = (
+    "engine.vertex_wave",
+    "engine.stab_wave",
+    "engine.edge_wave",
+    "traversal.frontier_expand",
+    "traversal.level_update",
+)
+
 _MAX_EVENTS = 1024  # bounded event log: growth/rehash escalations are rare
 
 _TRUTHY = ("1", "true", "on", "yes")
 
 
-def _summary_ms(samples: List[float]) -> Dict[str, float]:
+def _summary_ms(seconds: List[float]) -> Dict[str, float]:
     """count/total/mean/p50/p99/max over a duration list, in milliseconds."""
-    n = len(samples)
-    s = sorted(samples)
+    n = len(seconds)
+    s = sorted(seconds)
     total = sum(s)
     return {
         "count": n,
@@ -78,26 +97,30 @@ def _percentile_from_counts(counts: Dict[int, int], q: float) -> int:
 
 
 class _Span:
-    """Context manager timing one named section into a registry."""
+    """Context manager that annotates one named section for the profiler
+    and times it into a registry."""
 
-    __slots__ = ("_reg", "_name", "_t0")
+    __slots__ = ("_reg", "_name", "_ann", "_t0")
 
     def __init__(self, reg: "Registry", name: str):
         self._reg = reg
         self._name = name
+        self._ann = TraceAnnotation(name)
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self._reg._record_span(self._name, time.perf_counter() - self._t0)
+        self._ann.__exit__(*exc)
         return False
 
 
 class Registry:
-    """Thread-safe store of counters, gauges, histograms, samples, spans,
-    and bounded events.  One registry per observed run (a graph, a serving
+    """Thread-safe store of counters, gauges, histograms, spans and bounded
+    events.  One registry per observed run (a graph, a serving
     engine, a benchmark build); :meth:`dump` snapshots it as JSON-ready
     plain data."""
 
@@ -108,7 +131,6 @@ class Registry:
         self._counters: Dict[str, int] = {}
         self._gauges: Dict[str, float] = {}
         self._hists: Dict[str, Dict[int, int]] = {}
-        self._samples: Dict[str, List[float]] = {}
         self._spans: Dict[str, List[float]] = {}
         self._events: List[Dict] = []
         self._dropped_events = 0
@@ -132,11 +154,6 @@ class Registry:
                 v = int(v)
                 h[v] = h.get(v, 0) + 1
 
-    def observe(self, name: str, value: float) -> None:
-        """Record one float sample (e.g. a latency in ms) for percentiles."""
-        with self._lock:
-            self._samples.setdefault(name, []).append(float(value))
-
     def event(self, name: str, **fields) -> None:
         """Append one structured event (growth, rehash escalation, ...)."""
         with self._lock:
@@ -146,7 +163,8 @@ class Registry:
             self._events.append({"event": name, **fields})
 
     def span(self, name: str) -> _Span:
-        """``with reg.span("phase.route"): ...`` — wall-clock section timer."""
+        """``with reg.span("phase.route"): ...`` — a profiler annotation
+        and a wall-clock section timer."""
         return _Span(self, name)
 
     def _record_span(self, name: str, seconds: float) -> None:
@@ -163,16 +181,12 @@ class Registry:
             return dict(self._hists.get(name, {}))
 
     def percentile(self, name: str, q: float) -> Optional[float]:
-        """q-th percentile of a histogram (exact) or sample series, or
-        ``None`` when the name has no observations."""
+        """q-th percentile of a histogram (exact), or ``None`` when the
+        name has no observations."""
         with self._lock:
             h = self._hists.get(name)
             if h:
                 return float(_percentile_from_counts(dict(h), q))
-            s = self._samples.get(name)
-            if s:
-                ss = sorted(s)
-                return ss[min(len(ss) - 1, int((q / 100.0) * len(ss)))]
         return None
 
     def dump(self) -> Dict:
@@ -187,11 +201,6 @@ class Registry:
                     for k, v in sorted(self._hists.items())
                     if v
                 },
-                "samples": {
-                    k: _summary_ms([x / 1e3 for x in v])  # values already ms
-                    for k, v in sorted(self._samples.items())
-                    if v
-                },
                 "spans": {
                     k: _summary_ms(v) for k, v in sorted(self._spans.items()) if v
                 },
@@ -204,10 +213,10 @@ class Registry:
 
 class NoopRegistry:
     """API twin of :class:`Registry` with empty bodies — what every
-    instrumented path holds when observability is disabled."""
+    instrumented path holds when observability is disabled.  Its spans
+    still annotate the profiler's trace, and record nothing here."""
 
     enabled = False
-    _NULL = contextlib.nullcontext()
 
     def counter(self, name, n=1):
         pass
@@ -218,14 +227,11 @@ class NoopRegistry:
     def hist(self, name, values):
         pass
 
-    def observe(self, name, value):
-        pass
-
     def event(self, name, **fields):
         pass
 
     def span(self, name):
-        return self._NULL
+        return TraceAnnotation(name)
 
     def counters(self):
         return {}
@@ -304,10 +310,6 @@ def gauge(name: str, value: float) -> None:
 
 def hist(name: str, values) -> None:
     active().hist(name, values)
-
-
-def observe(name: str, value: float) -> None:
-    active().observe(name, value)
 
 
 def event(name: str, **fields) -> None:

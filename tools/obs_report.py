@@ -26,7 +26,7 @@ import sys
 from pathlib import Path
 
 BAR_WIDTH = 40
-SECTIONS = ("counters", "gauges", "histograms", "samples", "spans", "events")
+SECTIONS = ("counters", "gauges", "histograms", "spans", "events")
 
 
 def _bar(count: int, peak: int) -> str:
@@ -83,15 +83,14 @@ def render_registry(dump: dict, *, section: str | None = None,
                 print(f"      {val:>6}  {counts[val]:>8}  "
                       f"{_bar(counts[val], peak)}", file=out)
 
-    for part in ("samples", "spans"):
-        series = dump.get(part, {})
-        if want(part) and series:
-            print(f"  {part}:", file=out)
-            for name, s in series.items():
-                print(f"    {name}  n={s['count']} total={s['total_ms']:.2f}ms "
-                      f"mean={s['mean_ms']:.3f}ms p50={s['p50_ms']:.3f}ms "
-                      f"p99={s['p99_ms']:.3f}ms max={s['max_ms']:.3f}ms",
-                      file=out)
+    spans = dump.get("spans", {})
+    if want("spans") and spans:
+        print("  spans:", file=out)
+        for name, s in spans.items():
+            print(f"    {name}  n={s['count']} total={s['total_ms']:.2f}ms "
+                  f"mean={s['mean_ms']:.3f}ms p50={s['p50_ms']:.3f}ms "
+                  f"p99={s['p99_ms']:.3f}ms max={s['max_ms']:.3f}ms",
+                  file=out)
 
     events = dump.get("events", [])
     if want("events") and events:
