@@ -822,6 +822,11 @@ class WaitFreeGraph:
                     reg.hist(
                         "bfs.depth", [int(max(row.max(initial=0), 0)) for row in levels]
                     )
+                    reg.counter(
+                        "frontier.lanes_streamed",
+                        traversal.lanes_streamed(csr, len(pk), self.traversal_impl),
+                    )
+                    reg.counter("frontier.lane_capacity", csr.e_capacity)
             with reg.span("graph.bfs_batch.to_dicts"):
                 v_key = np.asarray(csr.v_key)
                 out = []

@@ -30,15 +30,20 @@ run on top.  This module is the dataflow analogue of their wait-free
 3. **Batched frontier BFS** (:func:`bfs_levels` / :func:`bfs_parents`) — a
    jitted ``lax.while_loop`` expands all S source frontiers simultaneously.
    Each level is one :func:`repro.kernels.frontier.frontier_expand` call —
-   gather edge sources against the frontier, scatter-*min* the proposing
-   source slot into edge destinations — so the same pass yields both the
-   new frontier (hit iff min proposer < NBR_INF) and the BFS *parent* of
-   every newly reached slot (the papers' ``GetPath`` pointer).  ``impl``
-   selects the XLA implementation (the default on every backend) or the
-   Pallas kernel in interpret mode; the two are bit-identical.  The iteration count is bounded
-   by the live vertex count (no path is longer), so the loop is
-   bounded-depth — the traversal analogue of the engines' wait-free locate
-   bound — and an edge-free snapshot skips the loop entirely.
+   every edge whose source is on the frontier proposes its source slot to
+   its destination, which keeps the *min* — so the same pass yields both
+   the new frontier (hit iff min proposer < NBR_INF) and the BFS *parent*
+   of every newly reached slot (the papers' ``GetPath`` pointer).  The XLA
+   implementation (the default on every backend) pulls: it sorts the
+   snapshot's lanes by destination once per call, before the loop
+   (:func:`repro.kernels.frontier.pull_view`), and each level reduces every
+   destination's in-edge segment with a segmented min-scan over the blocks
+   that hold a valid lane.  ``impl="kernel_interpret"`` runs the Pallas
+   kernel over the raw lanes instead; the two are bit-identical.  The
+   iteration count is bounded by the live vertex count (no path is
+   longer), so the loop is bounded-depth — the traversal analogue of the
+   engines' wait-free locate bound — and an edge-free snapshot skips the
+   loop entirely.
 
 4. **Query forms** — :func:`reachable` (pairwise u↝v for a whole batch),
    :func:`bfs_levels` (full level maps), :func:`bfs_parents` (levels +
@@ -75,7 +80,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.frontier import NBR_INF, frontier_expand
+from repro.kernels.frontier import NBR_INF, frontier_expand, pull_view
+from repro.kernels.frontier import ops as frontier_ops
 from repro.kernels.frontier.xla import edge_blocks
 
 # ambient telemetry (no-op unless a registry is active — see repro.obs and
@@ -497,13 +503,17 @@ def _bfs_from_slots(
     (levels, parents): i32[S, Cv] each, -1 for unreached / no parent.
     ``max_depth`` stops the expansion after that many levels (k-hop).
 
-    One :func:`frontier_expand` per level: the scatter-min result is both
-    the discovery mask (min < NBR_INF) and the parent pointer of every
-    newly reached slot.  On the device a level's ops carry the names
+    One :func:`frontier_expand` per level: its min proposer is both the
+    discovery mask (min < NBR_INF) and the parent pointer of every newly
+    reached slot.  The XLA expansion runs over a destination-sorted
+    :func:`~repro.kernels.frontier.pull_view` of the CSR, sorted once here,
+    before the loop, and shared by every level and source; it streams only
+    the lane blocks that hold one of the ``n_edges`` valid lanes.  On the
+    device the sort's ops carry the name ``traversal.pull_view``, a level's
     ``traversal.frontier_expand`` (the expansion) and
     ``traversal.level_update`` (the rest of the level).  An
     ``n_edges == 0`` snapshot returns the source-only maps without
-    entering the loop at all.
+    sorting or entering the loop at all.
     """
     cv = csr.v_capacity
     n_src = slot.shape[0]
@@ -520,25 +530,43 @@ def _bfs_from_slots(
         _, _, frontier, depth = carry
         return jnp.any(frontier[:, :cv]) & (depth < bound)
 
-    def body(carry):
-        levels, parents, frontier, depth = carry
-        with jax.named_scope("traversal.frontier_expand"):
-            nbr = frontier_expand(frontier, csr.src, csr.dst, impl=impl)
-        with jax.named_scope("traversal.level_update"):
-            new = (nbr != NBR_INF) & (levels == _NO_LEVEL)
-            new = new.at[:, cv].set(False)
-            levels = jnp.where(new, depth + 1, levels)
-            parents = jnp.where(new, nbr, parents)
-            return levels, parents, new, depth + 1
+    def expand_all(carry):
+        view = None
+        if frontier_ops.resolve(impl) == "xla":
+            with jax.named_scope("traversal.pull_view"):
+                view = pull_view(csr.src, csr.dst, cv + 1, n_src, n_live=csr.n_edges)
+
+        def body(carry):
+            levels, parents, frontier, depth = carry
+            with jax.named_scope("traversal.frontier_expand"):
+                nbr = frontier_expand(frontier, csr.src, csr.dst, impl=impl, view=view)
+            with jax.named_scope("traversal.level_update"):
+                new = (nbr != NBR_INF) & (levels == _NO_LEVEL)
+                new = new.at[:, cv].set(False)
+                levels = jnp.where(new, depth + 1, levels)
+                parents = jnp.where(new, nbr, parents)
+                return levels, parents, new, depth + 1
+
+        return jax.lax.while_loop(cond, body, carry)
 
     init = (levels, parents, frontier, jnp.int32(0))
     levels, parents, _, _ = jax.lax.cond(
         csr.n_edges == 0,
         lambda c: c,  # edge-free snapshot: sources are the whole answer
-        lambda c: jax.lax.while_loop(cond, body, c),
+        expand_all,
         init,
     )
     return levels[:, :cv], parents[:, :cv]
+
+
+def lanes_streamed(csr: TraversalCSR, n_src: int, impl: Optional[str] = None) -> int:
+    """Edge lanes one expansion of an ``n_src``-source query streams: the
+    XLA path's blocks that hold a valid lane, or the kernel's whole lane
+    capacity.  Reads ``n_edges`` back from the device."""
+    if frontier_ops.resolve(impl) != "xla":
+        return csr.e_capacity
+    block, _ = edge_blocks(csr.e_capacity, n_src)
+    return -(-int(csr.n_edges) // block) * block
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))

@@ -11,7 +11,8 @@ Kernels:
   * ``paged_attention`` — decode over wfgraph-managed block tables.
   * ``ssd_scan``        — Mamba-2 / RWKV-6 recurrence, VMEM-resident state.
   * ``hash_probe``      — graph-engine locate (VMEM-resident table).
-  * ``frontier``        — BFS frontier expansion (gather + scatter-min).
+  * ``frontier``        — BFS frontier expansion (a min proposer per destination:
+    a pull scan over destination-sorted lanes in XLA, a scatter-min in the kernel).
   * ``compact``         — state-maintenance compaction (prefix-sum stream
     compaction + claim-round quadratic-probe placement).
 """

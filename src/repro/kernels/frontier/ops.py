@@ -30,8 +30,16 @@ def frontier_expand(
     dst: jnp.ndarray,
     *,
     impl: str | None = None,
+    view: _xla.PullView | None = None,
 ) -> jnp.ndarray:
+    """i32[S, C]: min frontier source slot over in-edges, NBR_INF where none.
+
+    ``view`` is the XLA path's :func:`~.xla.pull_view` of ``(src, dst)``,
+    built once for many levels; the kernel takes ``src`` and ``dst`` as
+    they are."""
     impl = resolve(impl)
+    if impl == "xla" and view is not None:
+        return _xla.frontier_expand_pull(frontier, view)
     if impl == "xla":
         return _xla.frontier_expand_xla(frontier, src, dst)
     if impl == "kernel_interpret":

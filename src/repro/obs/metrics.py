@@ -46,6 +46,7 @@ DEVICE_SCOPES = (
     "engine.edge_wave",
     "traversal.frontier_expand",
     "traversal.level_update",
+    "traversal.pull_view",
 )
 
 _MAX_EVENTS = 1024  # bounded event log: growth/rehash escalations are rare

@@ -13,7 +13,14 @@ import jax.numpy as jnp
 
 from repro.core import SequentialGraph, WaitFreeGraph, bfs_parents, build_csr
 from repro.core.workloads import sample_batch
-from repro.kernels.frontier import NBR_INF, frontier_expand, frontier_expand_xla
+from repro.kernels.frontier import (
+    NBR_INF,
+    frontier_expand,
+    frontier_expand_xla,
+    pull_view,
+    xla as frontier_xla,
+)
+from repro.kernels.frontier.xla import frontier_expand_pull
 
 KEY_SPACE = 24
 
@@ -27,6 +34,8 @@ KEY_SPACE = 24
         (8, 128, 1000),   # lane-aligned C, ragged Ce (forces the extra block)
         (16, 257, 4096),  # multi-tile on both grid axes
         (5, 300, 2100),   # ragged S (padding rows) and Ce
+        (33, 70, 1000),   # sources past one packed word (XLA pull path)
+        (64, 40, 3000),   # two whole words
     ],
 )
 def test_frontier_expand_parity_random(S, C, Ce):
@@ -105,3 +114,67 @@ def test_bfs_through_kernel_matches_reference_and_oracle(seed):
     for s, row in zip(np.asarray(keys), np.asarray(lv_ker)):
         hit = np.nonzero(row >= 0)[0]
         assert {int(v_key[j]): int(row[j]) for j in hit} == o.bfs(int(s))
+
+
+# ---------------------------------------------------------------------------
+# the XLA pull path against a per-destination numpy min
+# ---------------------------------------------------------------------------
+
+
+def _numpy_min(frontier, src, dst):
+    """i32[S, C]: per destination, the least source slot on the frontier
+    among its in-edges; NBR_INF where none."""
+    out = np.full(frontier.shape, NBR_INF, np.int32)
+    for row, f in zip(out, frontier):
+        on = f[src]
+        np.minimum.at(row, dst[on], src[on])
+    return out
+
+
+def _csr_lanes(rng, cv, n_valid, ce, hub=None):
+    """CSR-shaped lanes over ``cv`` vertex slots: ``n_valid`` edges sorted
+    by source, then the invalid lanes (``src == dst == cv``); ``hub`` takes
+    half of the in-edges."""
+    src = np.sort(rng.integers(0, cv, n_valid)).astype(np.int32)
+    dst = rng.integers(0, cv, n_valid).astype(np.int32)
+    if hub is not None:
+        dst[rng.random(n_valid) < 0.5] = hub
+    pad = np.full(ce - n_valid, cv, np.int32)
+    return np.concatenate([src, pad]), np.concatenate([dst, pad])
+
+
+@pytest.mark.parametrize(
+    "S,cv,n_valid,ce,block_elems,density,hub",
+    [
+        (16, 300, 1000, 2048, None, 0.3, None),   # one block, invalid tail
+        (8, 200, 700, 1024, 2**9, 0.3, None),     # 64-lane blocks: 11 live of 16, last one ragged
+        (4, 100, 900, 1024, 2**9, 0.5, 7),        # a hub whose segment crosses several blocks
+        (16, 64, 1500, 2048, 2**10, 0.2, 63),     # a hub in the last column before the invalid tail
+        (5, 120, 0, 512, None, 0.4, None),        # every lane invalid
+        (16, 150, 600, 1024, 2**9, 0.0, None),    # empty frontier
+        (40, 90, 800, 1024, 2**11, 0.3, 5),       # two packed words, 32-lane blocks
+        (6, 80, 500, 700, 2**9, 0.3, 11),         # a lane count that is no power of two: padded view
+    ],
+)
+def test_pull_path_matches_numpy_min(monkeypatch, S, cv, n_valid, ce, block_elems, density, hub):
+    """The pull view over CSR-shaped lanes, streaming only the live blocks,
+    matches a per-destination numpy min; so do the raw-array entry point
+    (every lane live) and the Pallas kernel in interpret mode."""
+    if block_elems is not None:
+        monkeypatch.setattr(frontier_xla, "_BLOCK_ELEMS", block_elems)
+    rng = np.random.default_rng(S * 7919 + cv * 31 + n_valid)
+    src, dst = _csr_lanes(rng, cv, n_valid, ce, hub)
+    frontier = np.zeros((S, cv + 1), bool)  # column cv: the invalid lanes' slot
+    frontier[:, :cv] = rng.random((S, cv)) < density
+    want = _numpy_min(frontier, src, dst)
+    assert (want == NBR_INF).any()
+
+    f, s, d = jnp.asarray(frontier), jnp.asarray(src), jnp.asarray(dst)
+    view = pull_view(s, d, cv + 1, S, n_live=jnp.int32(n_valid))
+    block, _ = frontier_xla.edge_blocks(ce, S)
+    assert int(view.n_blocks) == -(-n_valid // block)
+    np.testing.assert_array_equal(np.asarray(frontier_expand_pull(f, view)), want)
+    np.testing.assert_array_equal(np.asarray(frontier_expand_xla(f, s, d)), want)
+    np.testing.assert_array_equal(
+        np.asarray(frontier_expand(f, s, d, impl="kernel_interpret")), want
+    )

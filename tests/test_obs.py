@@ -134,6 +134,26 @@ def test_obs_per_phase_spans_and_probe_health():
     assert occupied_v == sum(h["vertex"].values())
 
 
+def test_obs_frontier_lanes_streamed(monkeypatch):
+    """bfs_batch counts the edge lanes one expansion streams: only the blocks
+    that hold a valid lane, fewer than the snapshot's lane capacity."""
+    from repro.kernels.frontier import xla as frontier_xla
+
+    monkeypatch.setattr(frontier_xla, "_BLOCK_ELEMS", 2**10)  # 64-lane blocks at 16 sources
+    g, _ = _run(2, "waitfree", obs=True)
+    o, _ = _run(2, "waitfree", obs=False)
+    keys = list(range(KEY_SPACE))
+    assert g.bfs_batch(keys[:3]) == o.bfs_batch(keys[:3])
+    g.bfs_batch(keys[3:6])
+    csr = g.traversal_csr()
+    c = g.obs.counters()
+    streamed = -(-int(csr.n_edges) // 64) * 64
+    assert 0 < streamed < csr.e_capacity
+    assert c["frontier.lanes_streamed"] == 2 * streamed
+    assert c["frontier.lane_capacity"] == 2 * csr.e_capacity == 2 * 1024
+    assert "frontier.lanes_streamed" not in o.obs.counters()
+
+
 # ---------------------------------------------------------------------------
 # 2. shard-invariance of abstract counters + canonical directory histogram
 # ---------------------------------------------------------------------------

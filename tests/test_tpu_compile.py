@@ -17,6 +17,7 @@ imports this file.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,6 +90,13 @@ def test_frontier_expand_compiles(one_chip):
     )
     # the edge-blocked proposal tile bounds the working set far below HBM
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+    # the pull scan's int32 tiles keep the lane axis minor: a minor axis of
+    # 16 sources would pad to 128 lanes in every tile
+    arrays = re.findall(rf"s32\[{N_SOURCES},([\d,]+)\]\{{(\d+),", compiled.as_text())
+    wide = [(dims, minor) for dims, minor in arrays if int(dims.split(",")[-1]) >= 128]
+    assert wide
+    for dims, minor in wide:
+        assert int(minor) == dims.count(",") + 1, f"[{N_SOURCES},{dims}] has minor axis {minor}"
 
 
 @pytest.mark.parametrize("rows,n", [(3, V_CAP // 2), (6, E_CAP // 2)])

@@ -165,6 +165,32 @@ def test_bfs_levels_padding_lanes_are_inert():
     assert (lv[0] >= 0).sum() == 2 and (lv[2] >= 0).sum() == 1
 
 
+def _sort_sites(jaxpr, in_loop=False):
+    """One entry per ``sort`` in ``jaxpr`` and its sub-jaxprs: whether it
+    sits inside a ``while`` body."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            yield in_loop
+        loop = in_loop or eqn.primitive.name == "while"
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _sort_sites(inner, loop)
+
+
+def test_level_loop_sorts_once_outside_the_loop():
+    """The pull view's sort runs once per call, before the level loop; no
+    level sorts."""
+    import jax
+
+    g, o = WaitFreeGraph(64, 256), SequentialGraph()
+    _chain(g, o, [1, 2, 3, 4])
+    keys = np.asarray([1, 3, EMPTY_KEY, EMPTY_KEY], np.int32)
+    closed = jax.make_jaxpr(lambda c, k: bfs_levels(c, k, impl="xla"))(build_csr(g.state), keys)
+    assert list(_sort_sites(closed.jaxpr)) == [False]
+
+
 def test_cyclic_graph_terminates_and_matches():
     g, o = WaitFreeGraph(64, 64), SequentialGraph()
     _chain(g, o, [1, 2, 3])
